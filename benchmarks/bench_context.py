@@ -24,7 +24,10 @@ wrong axis (or an "other" partition) shows up as a ratio drifting from 1.
 This module keeps its import side-effect free: the 8-device XLA flag must be
 set before jax initialises, so ``run()`` (the ``benchmarks/run.py`` harness
 hook) re-executes this file as a subprocess with the flag in the
-environment, mirroring how launch/dryrun.py forces 512 hosts.
+environment, mirroring how launch/dryrun.py forces 512 hosts.  The child is
+pinned to the CPU (``JAX_PLATFORMS=cpu``): its mesh is the emulated one, and
+on an accelerator host the harness parent already holds the chip, which a
+second process cannot open.  Its output says so (``config.platform``).
 
 Usage::
 
@@ -46,12 +49,13 @@ OUT = "BENCH_context.json"
 
 
 def run():
-    """Harness hook: re-exec with 8 emulated devices, then emit the rows."""
+    """Harness hook: re-exec on 8 emulated CPU devices, then emit the rows."""
     from benchmarks.common import emit
 
     env = dict(os.environ)
     env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
                         + env.get("XLA_FLAGS", ""))
+    env["JAX_PLATFORMS"] = "cpu"
     subprocess.run([sys.executable, os.path.abspath(__file__)], check=True,
                    env=env)
     with open(OUT) as f:
@@ -177,6 +181,8 @@ def main():
     report = {
         "config": {"model": cfg.name, "batch": batch_size,
                    "seq_len": seq_len, "devices": n_dev,
+                   "platform": (f"{jax.devices()[0].platform}, {n_dev} "
+                                "emulated host devices"),
                    "kernel_mode": os.environ.get("REPRO_KERNEL_MODE",
                                                  "auto")},
         "points": points,

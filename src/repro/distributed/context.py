@@ -32,7 +32,9 @@ the ⊕ algebra, so its transpose is the reverse-direction ring.
 
 Both entry points fall back to the single-device ``kops`` ops when no
 context-parallel session is active (or the ``seq`` axis has size 1), so model
-code can call them unconditionally.
+code can call them unconditionally.  On a mesh whose ``seq`` axis has size 1
+the kernel path still runs inside a ``shard_map`` island over the batch
+(:func:`_batch_island`): GSPMD cannot partition a Mosaic kernel.
 """
 
 from __future__ import annotations
@@ -172,6 +174,30 @@ def context_parallel_session(seq: int):
 
     with mesh_plan_session(MeshPlan.host(seq=seq)) as cp:
         yield cp
+
+
+def _batch_island(cp: ContextParallel | None, fn, *args):
+    """``fn(*args)``, once per batch shard of ``cp.mesh`` on the kernel path.
+
+    GSPMD refuses to partition a Mosaic kernel, so on a mesh the Pallas
+    path runs inside ``shard_map``.  Every operand and result leads with
+    the batch dim, sharded as the rules shard ``"batch"`` (whole on each
+    device where that does not divide); all other dims are whole on each
+    device.  ``None`` operands pass through.  With no mesh, or on the jnp
+    path, ``fn`` is called as is.
+    """
+    if cp is None or kops.kernel_mode() == "jnp":
+        return fn(*args)
+    given = [a is not None for a in args]
+    operands = [a for a in args if a is not None]
+
+    def local(*xs):
+        it = iter(xs)
+        return fn(*(next(it) if g else None for g in given))
+
+    spec = P(cp.batch_axis(operands[0].shape[0]))
+    return shard_map(local, mesh=cp.mesh, in_specs=(spec,) * len(operands),
+                     out_specs=spec, check_rep=False)(*operands)
 
 
 # ---------------------------------------------------------------------------
@@ -474,8 +500,10 @@ def cp_aaren_prefix_attention(
     """
     cp = cp if cp is not None else current_cp()
     if cp is None or cp.size == 1:
-        return kops.aaren_prefix_attention(s, v, carry,
-                                           segment_ids=segment_ids)
+        return _batch_island(
+            cp, lambda s_, v_, c_, g_: kops.aaren_prefix_attention(
+                s_, v_, c_, segment_ids=g_),
+            s, v, carry, segment_ids)
     n = s.shape[-1]
     batch_shape = s.shape[:-1]
     d = v.shape[-1]
@@ -651,10 +679,12 @@ def cp_flash_mha(
     """
     cp = cp if cp is not None else current_cp()
     if cp is None or cp.size == 1:
-        return kops.flash_mha(q, k, v, causal=causal, window=window,
-                              scale=scale, q_lens=lengths, kv_lens=lengths,
-                              q_segment_ids=segment_ids,
-                              kv_segment_ids=segment_ids)
+        return _batch_island(
+            cp, lambda q_, k_, v_, len_, seg_: kops.flash_mha(
+                q_, k_, v_, causal=causal, window=window, scale=scale,
+                q_lens=len_, kv_lens=len_, q_segment_ids=seg_,
+                kv_segment_ids=seg_),
+            q, k, v, lengths, segment_ids)
     b, n, _, d = q.shape
     if k.shape[1] != n:
         raise ValueError("ring flash is self-attention: Nq must equal Nk")

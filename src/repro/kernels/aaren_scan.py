@@ -54,7 +54,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.scan_attention import NEG_INF
 
-DEFAULT_BLOCK_N = 256
+# Token tile: the lane width.  The in-block scan keeps about log2(block_n)
+# live (block_r, block_n, d) f32 temporaries in VMEM, d padded to 128 lanes.
+# On a TPU v5e (16 MB of scoped VMEM by default) block_n = 256 needs 20.6 MB
+# in the forward and 27.0 MB in the backward at every head dim from 64 to
+# 128; block_n = 128 compiles at every head dim the registered configs use
+# (32 to 256).  Below 128 the (block_r, block_n) score tile breaks the
+# (8, 128) block rule unless it spans the whole (padded) sequence.
+DEFAULT_BLOCK_N = 128
 DEFAULT_BLOCK_R = 8
 
 

@@ -17,11 +17,11 @@ in VMEM scratch across KV steps.  The forward also writes the logsumexp
 the backward re-materialise ``p_ij = exp(s_ij - L_i)`` tile-by-tile without
 ever holding the N x N matrix in HBM.
 
-True-length masking (DESIGN.md §Masking): every kernel reads per-batch-row
-``(q_len, kv_len)`` scalars from SMEM and masks score-tile positions at or
-beyond the true length to ``-inf`` *before* the online-softmax update (and
-re-applies the mask to the re-materialised probability tile in the
-backward).  Zero-padded K/V is **not** an identity under softmax — a padded
+True-length masking (DESIGN.md §Masking): every kernel reads its batch
+row's ``(q_len, kv_len)`` from the whole ``(B,)`` length arrays in SMEM and
+masks score-tile positions at or beyond the true length to ``-inf``
+*before* the online-softmax update (and re-applies the mask to the
+re-materialised probability tile in the backward).  Zero-padded K/V is **not** an identity under softmax — a padded
 key would get weight ``exp((q·0)·scale − m) > 0`` — so the mask is the only
 correct way to run a dense block grid at arbitrary N.  The wrappers pad all
 sequence dims up to the block multiple and the grid never shrinks its tiles
@@ -105,7 +105,7 @@ def _pad_dim(x: jax.Array, n_to: int, axis: int, value=0.0) -> jax.Array:
 
 
 def _as_lens(lens, batch: int, n: int) -> jax.Array:
-    """Normalise an optional per-row lengths array to (B, 1) int32 for SMEM.
+    """Normalise an optional per-row lengths array to (B,) int32 for SMEM.
 
     Clamped to [0, n]: an oversized length would unmask the zero-padded
     tail (whose keys score ``exp(-m) > 0`` and absorb real probability
@@ -114,14 +114,36 @@ def _as_lens(lens, batch: int, n: int) -> jax.Array:
     """
     if lens is None:
         lens = jnp.full((batch,), n, jnp.int32)
-    lens = jnp.clip(jnp.asarray(lens, jnp.int32), 0, n)
-    return lens.reshape(batch, 1)
+    return jnp.clip(jnp.asarray(lens, jnp.int32), 0, n).reshape(batch)
 
 
 def _lens_spec():
-    """(1, 1) per-batch-row scalar block in SMEM (scalars must be 2D there)."""
-    return pl.BlockSpec((1, 1), lambda ib, ih, j0, j1: (ib, 0),
-                        memory_space=pltpu.SMEM)
+    """The whole (B,) lengths array in SMEM; kernels index it by batch row."""
+    return pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _seg_specs(bq, bk, q_minor: bool):
+    """Segment-id tiles that meet the TPU (8, 128)-or-full block rule.
+
+    Query ids ride as a (B, Nq, 1) column, so a tile reads as (bq, 1);
+    key ids as a (B, 1, Nk) row, read as (1, bk).  Their comparison
+    broadcasts straight to the (bq, bk) score tile.  ``q_minor``: the grid
+    is (b, h, k-block, q-block), as in the dk/dv kernel.
+    """
+    if q_minor:
+        qmap = lambda ib, ih, jk, jq: (ib, jq, 0)
+        kmap = lambda ib, ih, jk, jq: (ib, 0, jk)
+    else:
+        qmap = lambda ib, ih, jq, jk: (ib, jq, 0)
+        kmap = lambda ib, ih, jq, jk: (ib, 0, jk)
+    return [pl.BlockSpec((1, bq, 1), qmap), pl.BlockSpec((1, 1, bk), kmap)]
+
+
+def _seg_operands(q_segment_ids, kv_segment_ids, n_qp, n_kp):
+    """(B, Nq_pad, 1) / (B, 1, Nk_pad) ids; padding keeps the padding id 0."""
+    segq = _pad_dim(jnp.asarray(q_segment_ids, jnp.int32), n_qp, 1)
+    segk = _pad_dim(jnp.asarray(kv_segment_ids, jnp.int32), n_kp, 1)
+    return [segq[:, :, None], segk[:, None, :]]
 
 
 def _block_relevant(q_start, k_start, block_q, block_k, causal, window,
@@ -131,7 +153,7 @@ def _block_relevant(q_start, k_start, block_q, block_k, causal, window,
     Causal/window bounds are static per tile; the true-length bounds come
     from the per-row SMEM scalars, so irrelevant tail blocks of a short row
     skip compute exactly like causally-masked blocks do.  ``seg_q``/``seg_k``
-    are this tile's packed-segment id vectors ((bq,) / (bk,)): a tile whose
+    are this tile's packed-segment ids ((bq, 1) / (1, bk)): a tile whose
     id *ranges* are disjoint cannot contain an equal pair, so cross-document
     tiles of a packed batch skip compute too — exact when ids are monotone
     along the row (the bin-packer emits them in order), conservative but
@@ -164,14 +186,13 @@ def _tile_mask(s_shape, q_start, k_start, causal, window, q_len, kv_len,
     if window is not None:
         mask &= k_pos > q_pos - window
     if seg_q is not None:
-        sq = seg_q[:, None]                       # (bq, 1)
-        mask &= (sq == seg_k[None, :]) & (sq != 0)
+        mask &= (seg_q == seg_k) & (seg_q != 0)  # (bq, 1) vs (1, bk)
     return mask
 
 
 def _flash_kernel(
     q_ref, k_ref, v_ref,      # (1, 1, bq, d), (1, 1, bk, d), (1, 1, bk, d)
-    qlen_ref, klen_ref,       # SMEM (1, 1) int32: this batch row's lengths
+    qlen_ref, klen_ref,       # SMEM (B,) int32 true lengths
     *rest,                    # [segq, segk,] o, lse + VMEM scratch m, l, acc
     scale: float, block_q: int, block_k: int, n_kv_blocks: int,
     causal: bool, window: int | None, has_segments: bool,
@@ -191,10 +212,11 @@ def _flash_kernel(
 
     q_start = jq * block_q
     k_start = jk * block_k
-    q_len = qlen_ref[0, 0]
-    kv_len = klen_ref[0, 0]
-    seg_q = segq_ref[0] if has_segments else None    # (bq,) int32
-    seg_k = segk_ref[0] if has_segments else None    # (bk,) int32
+    ib = pl.program_id(0)
+    q_len = qlen_ref[ib]
+    kv_len = klen_ref[ib]
+    seg_q = segq_ref[0] if has_segments else None    # (bq, 1) int32
+    seg_k = segk_ref[0] if has_segments else None    # (1, bk) int32
     relevant = _block_relevant(q_start, k_start, block_q, block_k,
                                causal, window, q_len, kv_len, seg_q, seg_k)
 
@@ -234,7 +256,7 @@ def _flash_kernel(
         l = l_scr[...]
         l_safe = jnp.where(l == 0.0, 1.0, l)
         o_ref[0, 0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_scr[...] + jnp.log(l_safe))[:, 0]
+        lse_ref[0, 0] = m_scr[...] + jnp.log(l_safe)
 
 
 @functools.partial(
@@ -308,14 +330,8 @@ def flash_attention(
     ]
     operands = [q, k, v, ql, kl]
     if has_segments:
-        # (1, block) id tiles; padded positions keep the padding id 0.
-        segq = _pad_dim(jnp.asarray(q_segment_ids, jnp.int32), n_qp, 1)
-        segk = _pad_dim(jnp.asarray(kv_segment_ids, jnp.int32), n_kp, 1)
-        in_specs += [
-            pl.BlockSpec((1, bq), lambda ib, ih, jq, jk: (ib, jq)),
-            pl.BlockSpec((1, bk), lambda ib, ih, jq, jk: (ib, jk)),
-        ]
-        operands += [segq, segk]
+        in_specs += _seg_specs(bq, bk, q_minor=False)
+        operands += _seg_operands(q_segment_ids, kv_segment_ids, n_qp, n_kp)
 
     o, lse = pl.pallas_call(
         kernel,
@@ -323,11 +339,13 @@ def flash_attention(
         in_specs=in_specs,
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda ib, ih, jq, jk: (ib, ih, jq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda ib, ih, jq, jk: (ib, ih, jq)),
+            # logsumexp as a (bq, 1) column: a (1, 1, bq) block over
+            # (B, H, N) would break the TPU block rule.
+            pl.BlockSpec((1, 1, bq, 1), lambda ib, ih, jq, jk: (ib, ih, jq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, n_qp, d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, n_qp), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, n_qp, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -336,7 +354,7 @@ def flash_attention(
         ],
         interpret=interpret,
     )(*operands)
-    o, lse = o[:, :, :n_q], lse[:, :, :n_q]
+    o, lse = o[:, :, :n_q], lse[:, :, :n_q, 0]
     return (o, lse) if return_residuals else o
 
 
@@ -349,7 +367,7 @@ def _recompute_p_ds(q, k, v, do, lse, delta, *, scale, q_start, k_start,
                     causal, window, q_len, kv_len, seg_q=None, seg_k=None):
     """Re-materialise the probability tile and dS tile from residuals.
 
-    q/do: (bq, d); k/v: (bk, d); lse/delta: (bq,).
+    q/do: (bq, d); k/v: (bk, d); lse/delta: (bq, 1).
     Returns p, ds: (bq, bk) f32.
     """
     s = jax.lax.dot_general(
@@ -358,7 +376,7 @@ def _recompute_p_ds(q, k, v, do, lse, delta, *, scale, q_start, k_start,
     mask = _tile_mask(s.shape, q_start, k_start, causal, window,
                       q_len, kv_len, seg_q, seg_k)
     s = jnp.where(mask, s, NEG_INF)
-    p = jnp.exp(s - lse[:, None])                    # (bq, bk)
+    p = jnp.exp(s - lse)                             # (bq, bk)
     # Empty rows carry lse == NEG_INF, where exp(NEG_INF - NEG_INF) = 1;
     # the mask pins them (and their dS) to exactly 0, mirroring the
     # forward's zero output for rows with no attendable key.
@@ -366,7 +384,7 @@ def _recompute_p_ds(q, k, v, do, lse, delta, *, scale, q_start, k_start,
     dp = jax.lax.dot_general(
         do, v, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)          # do_i · v_j
-    ds = p * (dp - delta[:, None])
+    ds = p * (dp - delta)
     return p, ds
 
 
@@ -390,8 +408,9 @@ def _flash_bwd_dq_kernel(
 
     q_start = jq * block_q
     k_start = jk * block_k
-    q_len = qlen_ref[0, 0]
-    kv_len = klen_ref[0, 0]
+    ib = pl.program_id(0)
+    q_len = qlen_ref[ib]
+    kv_len = klen_ref[ib]
     seg_q = segq_ref[0] if has_segments else None
     seg_k = segk_ref[0] if has_segments else None
     relevant = _block_relevant(q_start, k_start, block_q, block_k,
@@ -435,8 +454,9 @@ def _flash_bwd_dkv_kernel(
 
     q_start = jq * block_q
     k_start = jk * block_k
-    q_len = qlen_ref[0, 0]
-    kv_len = klen_ref[0, 0]
+    ib = pl.program_id(0)
+    q_len = qlen_ref[ib]
+    kv_len = klen_ref[ib]
     seg_q = segq_ref[0] if has_segments else None
     seg_k = segk_ref[0] if has_segments else None
     relevant = _block_relevant(q_start, k_start, block_q, block_k,
@@ -516,12 +536,11 @@ def flash_attention_bwd(
     v = _pad_dim(v, n_kp, 2)
     group = h // g
     has_segments = q_segment_ids is not None
-    if has_segments:
-        segq = _pad_dim(jnp.asarray(q_segment_ids, jnp.int32), n_qp, 1)
-        segk = _pad_dim(jnp.asarray(kv_segment_ids, jnp.int32), n_kp, 1)
-
-    # D_i = Σ_d do·o — one elementwise pass, shared by both kernels.
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
+    # D_i = Σ_d do·o — one elementwise pass, shared by both kernels.  It and
+    # the logsumexp enter as (bq, 1) columns (the TPU block rule).
+    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1,
+                    keepdims=True)
+    lse = lse[..., None]
 
     common = dict(scale=float(scale), block_q=bq, block_k=bk,
                   causal=causal, window=window, has_segments=has_segments)
@@ -532,18 +551,15 @@ def flash_attention_bwd(
         pl.BlockSpec((1, 1, bk, d),
                      lambda ib, ih, jq, jk: (ib, ih // group, jk, 0)),
         pl.BlockSpec((1, 1, bq, d), lambda ib, ih, jq, jk: (ib, ih, jq, 0)),
-        pl.BlockSpec((1, 1, bq), lambda ib, ih, jq, jk: (ib, ih, jq)),
-        pl.BlockSpec((1, 1, bq), lambda ib, ih, jq, jk: (ib, ih, jq)),
+        pl.BlockSpec((1, 1, bq, 1), lambda ib, ih, jq, jk: (ib, ih, jq, 0)),
+        pl.BlockSpec((1, 1, bq, 1), lambda ib, ih, jq, jk: (ib, ih, jq, 0)),
         _lens_spec(),
         _lens_spec(),
     ]
     operands = [q, k, v, do, lse, delta, ql, kl]
     if has_segments:
-        in_specs += [
-            pl.BlockSpec((1, bq), lambda ib, ih, jq, jk: (ib, jq)),
-            pl.BlockSpec((1, bk), lambda ib, ih, jq, jk: (ib, jk)),
-        ]
-        operands += [segq, segk]
+        in_specs += _seg_specs(bq, bk, q_minor=False)
+        operands += _seg_operands(q_segment_ids, kv_segment_ids, n_qp, n_kp)
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, n_kv_blocks=n_kp // bk,
@@ -568,16 +584,13 @@ def flash_attention_bwd(
         pl.BlockSpec((1, 1, bk, d),
                      lambda ib, ih, jk, jq: (ib, ih // group, jk, 0)),
         pl.BlockSpec((1, 1, bq, d), lambda ib, ih, jk, jq: (ib, ih, jq, 0)),
-        pl.BlockSpec((1, 1, bq), lambda ib, ih, jk, jq: (ib, ih, jq)),
-        pl.BlockSpec((1, 1, bq), lambda ib, ih, jk, jq: (ib, ih, jq)),
+        pl.BlockSpec((1, 1, bq, 1), lambda ib, ih, jk, jq: (ib, ih, jq, 0)),
+        pl.BlockSpec((1, 1, bq, 1), lambda ib, ih, jk, jq: (ib, ih, jq, 0)),
         _lens_spec(),
         _lens_spec(),
     ]
     if has_segments:
-        bwd_in_specs += [
-            pl.BlockSpec((1, bq), lambda ib, ih, jk, jq: (ib, jq)),
-            pl.BlockSpec((1, bk), lambda ib, ih, jk, jq: (ib, jk)),
-        ]
+        bwd_in_specs += _seg_specs(bq, bk, q_minor=True)
     dk_h, dv_h = pl.pallas_call(
         functools.partial(_flash_bwd_dkv_kernel, n_q_blocks=n_qp // bq,
                           **common),

@@ -10,7 +10,8 @@ Dispatch policy (DESIGN.md §Dispatch)
   its HLO cost analysis is what the roofline reads, and DESIGN.md §Perf
   documents the kernel-vs-jnp delta analytically.
 * ``REPRO_KERNEL_MODE`` env: ``auto`` (default) | ``pallas`` | ``interpret``
-  (kernels in interpret mode — used by kernel-parity tests) | ``jnp``.
+  (kernels in interpret mode — used by kernel-parity tests on the CPU; it
+  raises on a TPU, where it would hide the device) | ``jnp``.
 
 Gradients (DESIGN.md §Backward): both ops carry a ``custom_vjp`` that
 dispatches like the forward.  On the kernel path the forward saves compact
@@ -48,10 +49,21 @@ from repro.kernels import flash_attention as _flash_kernel
 from repro.obs.trace import span as _span
 
 
+KERNEL_MODES = ("auto", "pallas", "interpret", "jnp")
+
+
 def kernel_mode() -> str:
     mode = os.environ.get("REPRO_KERNEL_MODE", "auto")
+    if mode not in KERNEL_MODES:
+        raise ValueError(
+            f"REPRO_KERNEL_MODE={mode!r}; expected one of {KERNEL_MODES}")
+    on_tpu = jax.default_backend() == "tpu"
     if mode == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        return "pallas" if on_tpu else "jnp"
+    if mode == "interpret" and on_tpu:
+        raise RuntimeError(
+            "REPRO_KERNEL_MODE=interpret runs the Pallas interpreter on the "
+            "host; it is for CPU tests.  Unset it on a TPU.")
     return mode
 
 

@@ -53,8 +53,9 @@ from repro.roofline.analysis import (
 from repro.sharding import (
     MeshPlan, ShardingRules, param_shardings, plan_from_mesh, spec_for_axes,
     use_rules)
-from repro.train.optim import make_optimizer, opt_param_specs, warmup_cosine
-from repro.train.state import abstract_train_state, make_train_step
+from repro.train.optim import make_optimizer, warmup_cosine
+from repro.train.state import (
+    abstract_train_state, make_train_step, train_state_shardings)
 
 
 # ---------------------------------------------------------------------------
@@ -150,13 +151,10 @@ def _lower(cfg, shape, sr, *, batch: int, n_microbatches: int,
                     api.loss, opt, n_microbatches=n_microbatches,
                     grad_compression=grad_compression)
                 astate = abstract_train_state(api.abstract(), opt)
-                oshard = param_shardings(
-                    opt_param_specs(cfg.optimizer, pspecs), sr)
+                state_shardings = train_state_shardings(api, astate, sr)
+                oshard = state_shardings.opt_state
                 assert (jax.tree.structure(astate.opt_state)
                         == jax.tree.structure(oshard)), "opt shard mismatch"
-                state_shardings = type(astate)(
-                    step=NamedSharding(mesh, P()), params=pshard,
-                    opt_state=oshard)
                 # donate the train state: lets XLA update params/opt-state
                 # in place instead of double-buffering them (SPerf A3)
                 lowered = jax.jit(

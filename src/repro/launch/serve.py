@@ -9,6 +9,9 @@ event log, ``--metrics-out`` dumps the metrics-registry snapshot at exit,
 and ``--metrics-port`` serves live Prometheus text at ``/metrics`` (plus
 the snapshot document at ``/metrics.json``) while the engine runs.
 
+A slot quarantined for non-finite logits makes the run exit non-zero: on a
+device, poisoned logits are a fault to report, not a degraded success.
+
 Example::
 
     python -m repro.launch.serve --arch phi3-mini-3.8b --smoke \
@@ -25,6 +28,7 @@ import time
 import jax
 
 from repro.configs import get_config, smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.factory import build
 from repro.obs.events import EventLog, use_events
 from repro.obs.export import serve_metrics, write_snapshot
@@ -101,6 +105,7 @@ def main():
                     help="serve Prometheus text at /metrics on this port "
                          "while the engine runs (0 = ephemeral port)")
     args = ap.parse_args()
+    print(f"compile cache: {enable_compile_cache()}")
 
     # Ambient observability for the whole serve run: the engine's
     # instruments/events land here.  A registry is installed whenever any
@@ -219,6 +224,9 @@ def _run(args):
                 cache.save(args.prefix_cache_dir, 0)
                 print(f"[streaming] prefix cache saved to "
                       f"{args.prefix_cache_dir}")
+        if eng.n_quarantined:
+            raise SystemExit(f"[streaming] {eng.n_quarantined} slots "
+                             "quarantined for non-finite logits")
 
 
 def _run_router(args, api, params, sampler, prompts):
@@ -266,6 +274,10 @@ def _run_router(args, api, params, sampler, prompts):
         print(f"[router] shared prefix cache: {cst['entries']} entries, "
               f"hit rate {cst['hit_rate']:.0%}, "
               f"{cst['prefill_tokens_saved']} prefill tokens saved")
+    n_quarantined = sum(e.n_quarantined for e in router.engines)
+    if n_quarantined:
+        raise SystemExit(f"[router] {n_quarantined} slots quarantined for "
+                         "non-finite logits")
 
 
 if __name__ == "__main__":

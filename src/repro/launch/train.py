@@ -20,11 +20,12 @@ import numpy as np
 
 from repro.configs import get_config, smoke_config
 from repro.data.synthetic import SyntheticLMIterator
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.factory import build
 from repro.train.guard import GuardConfig
-from repro.train.loop import LoopConfig, run_train_loop
+from repro.train.loop import LoopConfig, loop_plan, run_train_loop
 from repro.train.optim import make_optimizer, warmup_cosine
-from repro.train.state import init_train_state, make_train_step
+from repro.train.state import build_train_state, make_train_step
 
 
 def main():
@@ -69,6 +70,7 @@ def main():
                     help="path of the metrics-snapshot JSON dumped at loop "
                          "exit (installs a metrics registry for the run)")
     args = ap.parse_args()
+    print(f"compile cache: {enable_compile_cache()}")
 
     cfg = (smoke_config(args.arch) if args.smoke
            else get_config(args.arch))
@@ -77,10 +79,6 @@ def main():
     print(f"arch={cfg.name} attn_mode={cfg.attn_mode} "
           f"pattern={cfg.effective_pattern()[:6]}")
 
-    params = api.init(jax.random.PRNGKey(args.seed))
-    n = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(params))
-    print(f"params: {n/1e6:.2f}M")
-
     guard = None
     if args.guard:
         guard = GuardConfig(backoff=args.guard_backoff,
@@ -88,7 +86,6 @@ def main():
                             spike_window=args.guard_spike_window)
     opt = make_optimizer(cfg.optimizer,
                          warmup_cosine(args.lr, args.steps // 10, args.steps))
-    state = init_train_state(params, opt, guard=guard)
     # donate the state: in-place param/opt updates (no double-buffering)
     step_fn = jax.jit(make_train_step(
         api.loss, opt, n_microbatches=args.microbatches,
@@ -105,6 +102,14 @@ def main():
         context_parallel=args.context_parallel,
         model_parallel=args.model_parallel, fsdp=args.fsdp,
         events=args.events, metrics_out=args.metrics_out)
+    # The state is created on the loop's mesh, each leaf where its sharding
+    # puts it (one device when the plan is trivial).
+    plan = loop_plan(loop_cfg)
+    state = build_train_state(
+        api, opt, jax.random.PRNGKey(args.seed), guard=guard,
+        mesh=plan.build_mesh() if plan is not None else None)
+    n = sum(int(np.prod(p.shape)) for p in jax.tree.leaves(state.params))
+    print(f"params: {n/1e6:.2f}M")
 
     def on_log(step, m):
         guard_s = (f" lr_scale={m['guard_lr_scale']:.3f}"

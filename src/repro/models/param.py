@@ -17,7 +17,8 @@ pure JAX.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable
+import functools
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -47,17 +48,30 @@ def _fan_in(shape: tuple[int, ...]) -> int:
     return int(np.prod(shape[:-1]))
 
 
-def _make_initializer(spec: ParamSpec) -> Callable[[jax.Array], jax.Array]:
-    if spec.init == "zeros":
-        return lambda key: jnp.zeros(spec.shape)
-    if spec.init == "ones":
-        return lambda key: jnp.ones(spec.shape)
-    if spec.init in ("normal", "embed", "query"):
-        std = spec.scale
-        if std is None:
-            std = 0.02 if spec.init in ("embed", "query") else 1.0 / np.sqrt(_fan_in(spec.shape))
-        return lambda key: std * jax.random.normal(key, spec.shape)
-    raise ValueError(f"unknown init {spec.init!r}")
+def _std(spec: ParamSpec) -> float:
+    if spec.scale is not None:
+        return float(spec.scale)
+    if spec.init in ("embed", "query"):
+        return 0.02
+    return float(1.0 / np.sqrt(_fan_in(spec.shape)))
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "init", "std", "dtype"))
+def _init_leaf(key, *, shape, init, std, dtype):
+    """One leaf, drawn and cast in a single program.
+
+    Jitted so the f32 draw, the scale and the cast fuse: a full-width
+    stacked leaf never sits on the device in f32 beside its cast copy.
+    """
+    if init == "zeros":
+        val = jnp.zeros(shape)
+    elif init == "ones":
+        val = jnp.ones(shape)
+    elif init in ("normal", "embed", "query"):
+        val = std * jax.random.normal(key, shape)
+    else:
+        raise ValueError(f"unknown init {init!r}")
+    return val.astype(dtype)
 
 
 def is_spec(x) -> bool:
@@ -70,8 +84,9 @@ def init_params(specs, key: jax.Array, param_dtype=jnp.float32):
     keys = jax.random.split(key, len(leaves))
     out = []
     for spec, k in zip(leaves, keys):
-        val = _make_initializer(spec)(k)
-        out.append(val.astype(spec.dtype or param_dtype))
+        out.append(_init_leaf(
+            k, shape=tuple(spec.shape), init=spec.init, std=_std(spec),
+            dtype=jnp.dtype(spec.dtype or param_dtype)))
     return jax.tree.unflatten(treedef, out)
 
 

@@ -137,8 +137,16 @@ class MeshPlan:
                    pod=2 if multi_pod else 1)
 
     def build_mesh(self, devices=None):
-        """Materialise the jax Mesh (first ``total`` devices row-major)."""
+        """Materialise the jax Mesh (first ``total`` devices row-major).
+
+        Every axis is ``Auto``: the logical-axis rules steer GSPMD through
+        sharding constraints, so arrays placed on the mesh must not carry
+        their sharding in their types (``make_mesh`` defaults to
+        ``Explicit``, under which plain ops like the embedding gather
+        refuse sharded operands).
+        """
         import jax
+        from jax.sharding import AxisType
 
         devs = devices if devices is not None else self.devices
         if devs is None:
@@ -148,7 +156,8 @@ class MeshPlan:
                 f"MeshPlan {self.describe()} needs {self.total} devices, "
                 f"got {len(devs)}")
         return jax.make_mesh(self.shape, self.axis_names,
-                             devices=list(devs)[:self.total])
+                             devices=list(devs)[:self.total],
+                             axis_types=(AxisType.Auto,) * len(self.shape))
 
     # ---- accounting hooks ------------------------------------------------
 

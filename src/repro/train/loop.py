@@ -118,6 +118,23 @@ class LoopResult:
     preempt_signal: int | None = None  # signal that triggered preemption
 
 
+def loop_plan(cfg: LoopConfig):
+    """The MeshPlan the loop runs under, or None on a single device.
+
+    One MeshPlan from the three LoopConfig knobs.  None (the common
+    single-device config: cp = mp = 1, fsdp auto) skips the session
+    entirely — no mesh is built.  Launchers build the train state on
+    ``loop_plan(cfg).build_mesh()`` so it starts where the loop needs it.
+    """
+    if cfg.context_parallel > 1 or cfg.model_parallel > 1 or cfg.fsdp > 1:
+        from repro.sharding import MeshPlan
+
+        return MeshPlan.host(
+            data=cfg.fsdp if cfg.fsdp > 0 else None,
+            seq=cfg.context_parallel, model=cfg.model_parallel)
+    return None
+
+
 def run_train_loop(
     train_step: Callable,            # (state, batch, key) -> (state, metrics)
     state: TrainState,
@@ -170,16 +187,7 @@ def run_train_loop(
     own_log = None
     own_reg = None
 
-    # One MeshPlan from the three LoopConfig knobs.  None (the common
-    # single-device config: cp = mp = 1, fsdp auto) skips the session
-    # entirely — no mesh is built, matching the old no-op scope.
-    plan = None
-    if cfg.context_parallel > 1 or cfg.model_parallel > 1 or cfg.fsdp > 1:
-        from repro.sharding import MeshPlan
-
-        plan = MeshPlan.host(
-            data=cfg.fsdp if cfg.fsdp > 0 else None,
-            seq=cfg.context_parallel, model=cfg.model_parallel)
+    plan = loop_plan(cfg)
 
     try:
         # Composed-mesh session (no-op scope when the plan is trivial):

@@ -42,6 +42,50 @@ def init_train_state(params, optimizer: Optimizer,
     )
 
 
+def build_train_state(api, optimizer: Optimizer, key: jax.Array, *,
+                      guard: GuardConfig | None = None,
+                      mesh=None) -> TrainState:
+    """Initialise params and optimizer state in one jitted program.
+
+    With a ``mesh``, every leaf is created where
+    :func:`train_state_shardings` puts it: no device ever holds the whole
+    state on the way.  ``optimizer`` must be the one
+    ``make_optimizer(api.cfg.optimizer, ...)`` builds.
+    """
+
+    def init(k):
+        return init_train_state(api.init(k), optimizer, guard=guard)
+
+    if mesh is None:
+        return jax.jit(init)(key)
+    from repro.sharding.rules import ShardingRules
+
+    shardings = train_state_shardings(api, jax.eval_shape(init, key),
+                                      ShardingRules(mesh))
+    return jax.jit(init, out_shardings=shardings)(key)
+
+
+def train_state_shardings(api, state_shape: TrainState, sr) -> TrainState:
+    """``NamedSharding`` tree of a train state under the rules ``sr``.
+
+    Params follow the logical-axis rules; the optimizer state follows
+    ``opt_param_specs(api.cfg.optimizer, ...)``; the step counter and the
+    guard state are replicated.
+    """
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.sharding.rules import param_shardings
+    from repro.train.optim import opt_param_specs
+
+    replicated = NamedSharding(sr.mesh, PartitionSpec())
+    return TrainState(
+        step=replicated,
+        params=param_shardings(api.specs(), sr),
+        opt_state=param_shardings(
+            opt_param_specs(api.cfg.optimizer, api.specs()), sr),
+        guard=jax.tree.map(lambda _: replicated, state_shape.guard))
+
+
 def abstract_train_state(abstract_params, optimizer: Optimizer,
                          guard: GuardConfig | None = None) -> TrainState:
     """ShapeDtypeStruct twin of :func:`init_train_state` (dry-run)."""

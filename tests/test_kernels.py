@@ -279,3 +279,20 @@ def test_flash_bwd_kernel_vs_reference(rng):
                               block_q=64, block_k=64, interpret=True)
     ref = flash_vjp_reference(q, k, v, do, causal=True)
     _grad_close(got, ref)
+
+
+def test_kernel_mode_refuses_interpret_on_tpu(monkeypatch):
+    """Interpret mode is for CPU tests: on a TPU it would hide the chip."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
+    with pytest.raises(RuntimeError, match="interpret"):
+        ops.kernel_mode()
+    monkeypatch.delenv("REPRO_KERNEL_MODE")
+    assert ops.kernel_mode() == "pallas"
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "jnp")
+    assert ops.kernel_mode() == "jnp"   # the on-chip reference path
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "pallsa")
+    with pytest.raises(ValueError, match="pallsa"):
+        ops.kernel_mode()

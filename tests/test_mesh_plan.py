@@ -215,3 +215,41 @@ def test_batch_axis_resolves_through_rules():
                          devices=jax.devices()[:8])
     cp = ContextParallel(mesh)
     assert cp.batch_axis(4) == ("pod", "data")
+
+
+def test_plan_mesh_axes_are_auto():
+    """Sharded arrays on a plan mesh must not carry their sharding in their
+    type: the rules steer GSPMD, and Explicit axes make plain ops such as
+    the embedding gather refuse sharded operands."""
+    from jax.sharding import AxisType
+
+    mesh = MeshPlan(devices=jax.devices()[:1]).build_mesh()
+    assert set(mesh.axis_types) == {AxisType.Auto}
+
+
+@pytest.mark.parametrize("mixer", ["aaren", "softmax"])
+def test_kernel_island_matches_plain_call(mixer, monkeypatch, rng):
+    """On a mesh whose seq axis is 1, the kernel path runs in a batch
+    shard_map island (GSPMD cannot partition a Mosaic kernel); it must
+    equal the plain call."""
+    from repro.distributed.context import cp_aaren_prefix_attention, cp_flash_mha
+    from repro.kernels import ops as kops
+
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "interpret")
+    cp = ContextParallel(MeshPlan(devices=jax.devices()[:1]).build_mesh())
+    b, h, n, d = 2, 2, 24, 16
+    seg = jnp.array([[1] * 10 + [2] * 14, [1] * 20 + [0] * 4], jnp.int32)
+    if mixer == "aaren":
+        s = jax.random.normal(rng, (b, h, n))
+        v = jax.random.normal(jax.random.fold_in(rng, 1), (b, h, n, d))
+        got, fin = cp_aaren_prefix_attention(s, v, segment_ids=seg, cp=cp)
+        want, fin_w = kops.aaren_prefix_attention(s, v, segment_ids=seg)
+        np.testing.assert_allclose(fin.w, fin_w.w, rtol=1e-6, atol=1e-6)
+    else:
+        q, k, v = (jax.random.normal(jax.random.fold_in(rng, i), (b, n, h, d))
+                   for i in range(3))
+        lens = jnp.array([24, 17], jnp.int32)
+        got = cp_flash_mha(q, k, v, lengths=lens, segment_ids=seg, cp=cp)
+        want = kops.flash_mha(q, k, v, q_lens=lens, kv_lens=lens,
+                              q_segment_ids=seg, kv_segment_ids=seg)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)

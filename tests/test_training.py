@@ -29,6 +29,7 @@ from repro.train.optim import (
 )
 from repro.train.state import (
     abstract_train_state,
+    build_train_state,
     init_train_state,
     make_train_step,
 )
@@ -262,3 +263,24 @@ def test_data_iterator_state_roundtrip_mid_epoch():
         assert it2.state() == snap
         for want in tail:
             np.testing.assert_array_equal(next(it2)["tokens"], want)
+
+
+def test_build_train_state_places_leaves_at_init():
+    """One jitted init: the same values as the eager path, and on a mesh
+    every leaf is created with its rule-derived NamedSharding."""
+    from jax.sharding import NamedSharding
+
+    from repro.sharding import MeshPlan
+
+    cfg, api = _tiny()
+    opt = make_optimizer(cfg.optimizer, warmup_cosine(1e-3, 1, 10))
+    key = jax.random.PRNGKey(3)
+    want = init_train_state(api.init(key), opt)
+    got = build_train_state(api, opt, key)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    mesh = MeshPlan(devices=jax.devices()[:1]).build_mesh()
+    placed = build_train_state(api, opt, key, mesh=mesh)
+    for leaf in jax.tree.leaves((placed.params, placed.opt_state)):
+        assert isinstance(leaf.sharding, NamedSharding)
+        assert leaf.sharding.mesh == mesh
