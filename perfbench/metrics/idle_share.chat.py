@@ -1,0 +1,3 @@
+"""Per cent of the traced window with no operation on the device."""
+
+from lib.readers import idle_share as read  # noqa: F401
