@@ -15,8 +15,10 @@ primitives instead of private dicts of ``perf_counter()`` bookkeeping:
 * :mod:`repro.obs.trace` — ``jax.profiler`` ``TraceAnnotation`` /
   ``named_scope`` wrappers gated by ``REPRO_TRACE``; compile-time no-ops
   when off.  Wrapped around the kernel dispatch boundary, the cp carry
-  exchange / ring-flash rotation, and the engine's schedule/step/sample
-  phases so an xprof trace attributes device time to named phases.
+  exchange / ring-flash rotation, every host phase of the engine's tick
+  and the packer, so an xprof trace attributes device time and idle gaps
+  to named phases; garbage collections and JAX's trace / compile phases
+  get spans of their own while tracing is on.
 * :mod:`repro.obs.export` — Prometheus-style text exposition + JSON
   snapshot, served from ``launch/serve.py`` and dumped at loop exit from
   ``train/loop.py``.

@@ -19,6 +19,12 @@ Design constraints (DESIGN.md §Observability):
 Naming scheme: ``<subsystem>_<quantity>[_<unit>]`` with ``_total`` for
 counters — ``train_step_time_s``, ``serve_ttft_s``, ``serve_shed_total``.
 
+JAX's own compile events are counted too, once a registry has been
+installed: ``jax_traces_total`` (each trace of a jitted function) and
+``jax_backend_compiles_total`` (each backend compile or compile-cache
+load), from a ``jax.monitoring`` listener registered once.  Either rising
+in steady serving means a recompile on the hot path.
+
 Labels: every accessor takes ``labels={"replica": "0"}``; each distinct
 label set is its own series, stored under the canonical key
 ``name{k="v",...}`` (keys sorted, values stringified).  The replicated
@@ -268,9 +274,33 @@ class MetricsRegistry:
 
 _REGISTRY: MetricsRegistry | None = None
 
+#: jax.monitoring duration event -> the counter it feeds
+JAX_COUNTERS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax_traces_total",
+    "/jax/core/compile/backend_compile_duration":
+        "jax_backend_compiles_total",
+}
+_WATCHING_JAX = False
+
+
+def _count_jax_event(event: str, duration_secs: float, **kwargs) -> None:
+    name = JAX_COUNTERS.get(event)
+    if name is not None:
+        inc(name)
+
+
+def _watch_jax() -> None:
+    global _WATCHING_JAX
+    if not _WATCHING_JAX:
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_count_jax_event)
+        _WATCHING_JAX = True
+
 
 def install(reg: MetricsRegistry) -> MetricsRegistry:
     global _REGISTRY
+    _watch_jax()
     _REGISTRY = reg
     return reg
 
@@ -288,6 +318,7 @@ def current() -> MetricsRegistry | None:
 def use_metrics(reg: MetricsRegistry):
     """Scoped install — the test-friendly form of :func:`install`."""
     global _REGISTRY
+    _watch_jax()
     prev = _REGISTRY
     _REGISTRY = reg
     try:

@@ -580,10 +580,20 @@ class StreamingEngine:
         """One engine tick: admit, advance the mixed batch, sample.
 
         Returns the number of tokens emitted this tick (0 when idle).
+        Under tracing, the whole tick is the span ``engine.tick``, and each
+        phase a span inside it: ``engine.schedule`` (``engine.expire``,
+        ``engine.admit``), ``engine.step`` (the dispatch), ``engine.wait``,
+        ``engine.guard``, ``engine.sample`` and ``engine.reset``.
         """
+        with obs_trace.span("engine.tick"):
+            return self._tick()
+
+    def _tick(self) -> int:
         with obs_trace.span("engine.schedule"):
-            self._expire_deadlines()
-            self._admit()
+            with obs_trace.span("engine.expire"):
+                self._expire_deadlines()
+            with obs_trace.span("engine.admit"):
+                self._admit()
             n_active = sum(s is not None for s in self.active)
             obs_metrics.set_gauge("serve_queue_depth", len(self.queue))
             obs_metrics.set_gauge("serve_slot_occupancy",
@@ -626,20 +636,26 @@ class StreamingEngine:
         # (S, 1, V) last-valid logits — already host-bound for sampling —
         # and reset ONLY the poisoned rows.  Healthy batch-mates never see a
         # different code path, so their outputs stay byte-identical.
-        poisoned = np.zeros((self.n_slots,), bool)
         if self.guard_logits:
-            finite_rows = np.isfinite(
-                np.asarray(last)).reshape(self.n_slots, -1).all(axis=1)
-            for i, slot in enumerate(self.active):
-                if slot is not None and not finite_rows[i]:
-                    poisoned[i] = True
-                    self.errors[slot.request_id] = ERR_POISONED
-                    self.n_quarantined += 1
-                    self.active[i] = None
-                    obs_metrics.inc("serve_quarantine_total")
-                    self._request_done(slot.request_id, "quarantine", slot=i)
-        if poisoned.any():
-            self.states = self._reset_fn(self.states, jnp.asarray(poisoned))
+            # The tick's first host read, in two parts: the wait for the
+            # step, then the copy.  The split adds no synchronisation.
+            with obs_trace.span("engine.wait"):
+                jax.block_until_ready(last)
+            with obs_trace.span("engine.guard"):
+                finite_rows = np.isfinite(
+                    np.asarray(last)).reshape(self.n_slots, -1).all(axis=1)
+                poisoned = np.zeros((self.n_slots,), bool)
+                for i, slot in enumerate(self.active):
+                    if slot is not None and not finite_rows[i]:
+                        poisoned[i] = True
+                        self.errors[slot.request_id] = ERR_POISONED
+                        self.n_quarantined += 1
+                        self.active[i] = None
+                        obs_metrics.inc("serve_quarantine_total")
+                        self._request_done(slot.request_id, "quarantine",
+                                           slot=i)
+                if poisoned.any():
+                    self._reset(poisoned)
 
         emitted = 0
         completed = np.zeros((self.n_slots,), bool)
@@ -693,7 +709,7 @@ class StreamingEngine:
             # Slot-carry lifecycle invariant (DESIGN.md §Serving): a freed
             # slot's carry returns to the ⊕-identity init in the same tick,
             # never lingering until the next admit.
-            self.states = self._reset_fn(self.states, jnp.asarray(completed))
+            self._reset(completed)
         return emitted
 
     def run(self) -> dict[int, list[int]]:
@@ -771,7 +787,7 @@ class StreamingEngine:
                                active=False)
         self.queue = []
         if occupied.any():
-            self.states = self._reset_fn(self.states, jnp.asarray(occupied))
+            self._reset(occupied)
         if descs:
             obs_metrics.inc("serve_migrated_total", len(descs))
         obs_metrics.set_gauge("serve_queue_depth", 0)
@@ -979,7 +995,7 @@ class StreamingEngine:
         # (or was taken by a buggy build).
         free = np.asarray([s is None for s in self.active], bool)
         if free.any():
-            self.states = self._reset_fn(self.states, jnp.asarray(free))
+            self._reset(free)
         # Absolute perf_counter() values don't survive a restart, but wiping
         # the latency maps outright made every restored request's terminal
         # event drop ``total_s`` and its first token miss the TTFT
@@ -1045,6 +1061,12 @@ class StreamingEngine:
             self._gather_fn = _jit(gather)
             self._inject_fn = _jit(inject)
         return self._gather_fn, self._inject_fn
+
+    def _reset(self, mask: np.ndarray) -> None:
+        """Return the carries of the slots in ``mask`` (S,) to the
+        ⊕-identity init."""
+        with obs_trace.span("engine.reset"):
+            self.states = self._reset_fn(self.states, jnp.asarray(mask))
 
     def _sample_ready(self, last, ready: list[int]) -> dict[int, int]:
         """Sample the rows in ``ready``; returns {row: token id}.
@@ -1118,7 +1140,7 @@ class StreamingEngine:
             # dead request's carry in ``self.states`` until the next admit
             # violated the lifecycle invariant (a snapshot taken in the gap
             # captured another tenant's state in a "free" slot).
-            self.states = self._reset_fn(self.states, jnp.asarray(expired))
+            self._reset(expired)
 
     def _admit(self):
         """Move queued requests into free slots.
@@ -1127,11 +1149,21 @@ class StreamingEngine:
         resets eagerly — the lifecycle invariant), so admission only
         *writes* state for prefix-cache hits: the cached carry is injected
         into the slot row and the matched prompt tokens are skipped.
+
+        Each admission reports its queue wait, submission (or injection,
+        or restore) to slot: the ``request_admitted`` event and the
+        ``serve_queue_wait_s`` histogram.
         """
+        now = time.perf_counter()
         for i in range(self.n_slots):
             if self.active[i] is not None or not self.queue:
                 continue
             q = self.queue.pop(0)
+            # Every queued request has a submission time.
+            wait = now - self.submitted_at.get(q.request_id, now)
+            obs_metrics.observe("serve_queue_wait_s", wait)
+            obs_events.emit("request_admitted", rid=q.request_id, slot=i,
+                            queue_wait_s=wait)
             slot = _Slot(request_id=q.request_id, pending=q.pending,
                          tokens=list(q.tokens), remaining=q.remaining,
                          n_sampled=q.n_sampled,

@@ -7,6 +7,7 @@ training run and one serving run, each leaving behind a schema-valid JSONL
 event log and a metrics snapshot with the named instruments.
 """
 
+import gc
 import json
 import threading
 import urllib.request
@@ -239,14 +240,87 @@ def test_span_on_wraps_named_scope():
         with s1:            # enters named_scope + TraceAnnotation
             y = jax.numpy.ones((2,)) * 2
         assert float(y.sum()) == 4.0
-
-        @obs_trace.annotate("fn")
-        def f(v):
-            return v + 1
-
-        assert f(1) == 2
     finally:
         obs_trace.set_enabled(prev)
+
+
+def test_tracing_off_costs_no_span_no_gc_hook_and_no_event(model, rng):
+    """Off is free: every span of a tick is the shared null object, no GC
+    callback is installed, and an engine with no sink emits nothing."""
+    prev = obs_trace.set_enabled(False)
+    try:
+        for name in ("engine.schedule", "engine.expire", "engine.admit",
+                     "engine.step", "engine.wait", "engine.guard",
+                     "engine.reset", "engine.sample", "data.pack"):
+            assert obs_trace.span(name) is obs_trace._NULL
+        assert obs_trace._gc_span not in gc.callbacks
+        api, params = model
+        idle = EventLog(path=None)               # never installed
+        assert obs_events.current() is None
+        eng = StreamingEngine(api, params, n_slots=2)
+        eng.submit(jax.random.randint(rng, (5,), 0, 64), 2)
+        eng.run()
+        assert [r["kind"] for r in idle.records] == ["run_meta"]
+    finally:
+        obs_trace.set_enabled(prev)
+
+
+def test_gc_span_hook_follows_the_trace_gate():
+    prev = obs_trace.set_enabled(True)
+    try:
+        assert gc.callbacks.count(obs_trace._gc_span) == 1
+        obs_trace.set_enabled(True)              # idempotent
+        assert gc.callbacks.count(obs_trace._gc_span) == 1
+        gc.collect()                             # opens and closes its span
+        assert getattr(obs_trace._OPEN, "stack", []) == []
+    finally:
+        obs_trace.set_enabled(False)
+        assert obs_trace._gc_span not in gc.callbacks
+        obs_trace.set_enabled(prev)
+
+
+def test_jax_traces_total_rises_once_then_stays_flat(model, rng):
+    """A recompile in steady serving is a bug: the first tick traces the
+    engine's step, ten more ticks of the same shapes trace nothing."""
+    api, params = model
+    reg = MetricsRegistry()
+    with use_metrics(reg):
+        eng = StreamingEngine(api, params, n_slots=2, chunk=4)
+        for i in range(2):       # prefilled and sampled on the first tick
+            eng.submit(jax.random.randint(jax.random.fold_in(rng, i), (4,),
+                                          0, 64), 20)
+        eng.step()
+        traces = reg.peek("jax_traces_total")
+        compiles = reg.peek("jax_backend_compiles_total")
+        assert traces >= 1 and compiles >= 1
+        for _ in range(10):
+            eng.step()
+        assert not eng.finished                 # no new shape (no reset)
+        assert reg.peek("jax_traces_total") == traces
+        assert reg.peek("jax_backend_compiles_total") == compiles
+    jax.jit(lambda x: x + 1)(1.0)               # no registry: not counted
+    assert reg.peek("jax_traces_total") == traces
+
+
+def test_request_admitted_carries_rid_slot_and_queue_wait(model, rng):
+    api, params = model
+    reg = MetricsRegistry()
+    log = EventLog(path=None)
+    with use_metrics(reg), use_events(log):
+        eng = StreamingEngine(api, params, n_slots=1)
+        rids = [eng.submit(jax.random.randint(rng, (3,), 0, 64), 2)
+                for _ in range(3)]
+        eng.run()
+    admitted = [r["data"] for r in log.records
+                if r["kind"] == "request_admitted"]
+    assert [a["rid"] for a in admitted] == rids
+    assert all(a["slot"] == 0 and a["queue_wait_s"] >= 0 for a in admitted)
+    # the third request waited for both before it
+    assert admitted[2]["queue_wait_s"] >= admitted[1]["queue_wait_s"]
+    hist = reg.snapshot()["histograms"]["serve_queue_wait_s"]
+    assert hist["count"] == 3
+    assert hist["sum"] == pytest.approx(
+        sum(a["queue_wait_s"] for a in admitted))
 
 
 # ---------------------------------------------------------------------------
