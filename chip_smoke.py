@@ -246,9 +246,8 @@ def serve_phase(seed: int) -> None:
 
     print(f"[serve] engine warm-up (compile) {eng.warmup():.1f}s (set-up), "
           f"chunk {eng.chunk}", flush=True)
-    tokens = jnp.zeros((SERVE_SLOTS, eng.chunk), jnp.int32)
-    lengths = jnp.ones((SERVE_SLOTS,), jnp.int32)
-    require_kernel(eng._step_fn.lower(params, tokens, lengths, eng.states)
+    batch = jnp.zeros((SERVE_SLOTS, eng.chunk + 3), jnp.int32)
+    require_kernel(eng._step_fn.lower(params, batch, eng.states, eng.key)
                    .compile().as_text(), "engine step")
     for p in prompts:
         eng.submit(p, MAX_NEW)

@@ -11,11 +11,13 @@ Two serving modes, matching the paper's efficiency analysis (§4.5):
   the ``n_slots`` persistent decode slots feeds next, and exactly two
   fixed-shape jitted functions touch the device —
 
-  - ``step(params, tokens (S, C), lengths (S,), states)`` advances a *mixed*
+  - ``step(params, batch (S, C + 3), states, key)`` advances a *mixed*
     batch: mid-prefill slots consume up to C prompt tokens, decoding slots
     carry one valid token, padding is ⊕-identity in the carry scan.  One
     trace per (S, C), ever — no per-prompt-length recompilation, and a
     refill longer than one chunk never stalls the decode of other slots.
+    The step also guards and samples each row's last-valid logits, so the
+    host reads back S token ids and S finite flags, never the logits.
   - ``reset(states, mask (S,))`` re-initialises freed slots' carries in
     place, addressed by the explicit batch-axis metadata of
     :func:`repro.models.lm.lm_state_batch_axes` (shape-matching heuristics
@@ -84,12 +86,13 @@ def request_key(base_key: jax.Array, request_id: int, step: int) -> jax.Array:
     return jax.random.fold_in(jax.random.fold_in(base_key, request_id), step)
 
 
-# One fused (vmapped + jitted) sampling call per sampler: per-row key
-# derivation + the sample itself run on device, replacing B eager host
-# round-trips (~1ms each — the dominant cost of an engine tick at 8 slots)
-# with one.  Keyed weakly per sampler function; jax.jit is used directly
-# (not `_jit`) because the cache outlives any single engine, so per-engine
-# trace-count tests must not see it.
+# One vmapped + jitted sampling function per sampler: per-row key
+# derivation and the sample itself, on device.  The streaming engine traces
+# it into its step, so a tick's tokens come back with the step's output;
+# generate() calls it as a program of its own after each decode step.
+# Keyed weakly per sampler function; jax.jit is used directly (not `_jit`)
+# because the cache outlives any single engine, so per-engine trace-count
+# tests must not see it.
 _BATCHED_SAMPLERS: "weakref.WeakKeyDictionary[Callable, Callable]" = (
     weakref.WeakKeyDictionary())
 
@@ -116,8 +119,10 @@ def _sample_rows(sampler: Callable, logits: jax.Array, base_key: jax.Array,
 
     ``jit_safe`` samplers (the built-ins) take one fused vmapped call;
     custom samplers fall back to eager per-row calls so instrumented
-    samplers see concrete keys.  Both engines route through here, so
-    streaming and wave generation stay sample-for-sample identical.
+    samplers see concrete keys.  Wave generation samples here; the
+    streaming engine runs the same vmapped sampler inside its step (or
+    this eager path for custom samplers), so the two stay
+    sample-for-sample identical.
     """
     if getattr(sampler, "jit_safe", False):
         return _batched_sampler(sampler)(
@@ -421,8 +426,20 @@ class StreamingEngine:
         batch_axes = lm_state_batch_axes(cfg)
         self._batch_axes = batch_axes
 
-        def step(pr, tokens, lengths, states):
-            """(S, C) tokens + per-slot valid lengths -> last-valid logits."""
+        def step(pr, batch, states, key):
+            """One tick: advance the mixed batch, then guard and sample.
+
+            ``batch`` (S, C + 3) int32 packs each row's C tokens, its valid
+            length, its request id and its sample index (the key schedule
+            of :func:`request_key`), so the tick uploads one array.  Returns
+            ``(picked, last, new_states)``: ``picked`` (S, 2) int32 holds
+            each row's sampled token and whether its logits are all
+            finite; ``last`` (S, 1, V) is the last-valid logits, left on
+            the device for samplers that are not ``jit_safe``.
+            """
+            tokens = batch[:, :chunk]
+            lengths = batch[:, chunk]
+            rids, steps = batch[:, chunk + 1], batch[:, chunk + 2]
             mask = jnp.arange(chunk)[None, :] < lengths[:, None]
             logits, new_states = lm_prefill_chunk(
                 cfg, pr, tokens, states, length_mask=mask)
@@ -453,7 +470,21 @@ class StreamingEngine:
             last_idx = jnp.maximum(lengths - 1, 0)
             last = jnp.take_along_axis(
                 logits, last_idx[:, None, None], axis=1)  # (S, 1, V)
-            return last, new_states
+            # Guard and sample exactly the returned bits: the barrier keeps
+            # XLA from fusing the sampler into the unembedding, so a token
+            # equals what _sample_rows draws from `last` (wave parity).
+            last = jax.lax.optimization_barrier(last)
+            finite = jnp.isfinite(last).all(axis=(1, 2))
+            # Read at trace time (warmup), not captured at construction, so
+            # a sampler swapped in before warmup() is the one traced.
+            sample = self._batched_sample
+            if sample is None:   # not jit_safe: the host samples `last`
+                toks = jnp.zeros((n_slots,), jnp.int32)
+            else:
+                toks = sample(last, key, rids, steps)[:, 0]
+            picked = jnp.stack([toks.astype(jnp.int32),
+                                finite.astype(jnp.int32)], axis=1)
+            return picked, last, new_states
 
         def reset(states, mask):
             """Zero the carries of slots where mask (S,) is True."""
@@ -469,9 +500,9 @@ class StreamingEngine:
 
         self._step_fn = _jit(step)
         self._reset_fn = _jit(reset)
-        # jit-safe samplers batch all slots' samples into one fused call
-        # per tick; custom samplers keep the eager per-row path (concrete
-        # keys for instrumented samplers — tests rely on this).
+        # jit-safe samplers sample inside the step; custom samplers keep
+        # the eager per-row path on the step's logits (concrete keys for
+        # instrumented samplers — tests rely on this).
         self._batched_sample = (_batched_sampler(sampler)
                                 if getattr(sampler, "jit_safe", False)
                                 else None)
@@ -559,13 +590,11 @@ class StreamingEngine:
         Returns the wall seconds spent (≈ compile time).
         """
         t0 = time.perf_counter()
-        tokens = jnp.zeros((self.n_slots, self.chunk), jnp.int32)
-        lengths = jnp.ones((self.n_slots,), jnp.int32)
-        last, states = self._step_fn(self.params, tokens, lengths, self.states)
+        batch = np.zeros((self.n_slots, self.chunk + 3), np.int32)
+        batch[:, self.chunk] = 1
+        picked, last, states = self._step_fn(
+            self.params, jnp.asarray(batch), self.states, self.key)
         states = self._reset_fn(states, jnp.zeros((self.n_slots,), bool))
-        if self._batched_sample is not None:
-            zeros = jnp.zeros((self.n_slots,), jnp.int32)
-            self._batched_sample(last, self.key, zeros, zeros)
         if self.prefix_cache is not None:
             # The cache's gather/inject entry points compile here too — the
             # first cache hit must not pay jit compile inside a TTFT.
@@ -573,7 +602,7 @@ class StreamingEngine:
             carry = gather(states, jnp.int32(0))
             states = inject(states, carry,
                             jnp.zeros((self.n_slots,), bool))
-        jax.block_until_ready((last, states))
+        jax.block_until_ready((picked, last, states))
         return time.perf_counter() - t0
 
     def step(self) -> int:
@@ -606,12 +635,17 @@ class StreamingEngine:
             # preserving the lifecycle invariant between ticks.  (They used
             # to be fed token 0 with lengths == 1, quietly accumulating
             # garbage that the next admit's reset had to paper over.)
-            tokens = np.zeros((self.n_slots, self.chunk), np.int32)
-            lengths = np.zeros((self.n_slots,), np.int32)
+            # One host array per tick (the step's only upload): tokens,
+            # then each row's valid length, request id and sample index.
+            c = self.chunk
+            batch = np.zeros((self.n_slots, c + 3), np.int32)
+            tokens, lengths = batch[:, :c], batch[:, c]
             prefill_toks, decode_toks = 0, 0
             for i, slot in enumerate(self.active):
                 if slot is None:
                     continue
+                batch[i, c + 1] = slot.request_id
+                batch[i, c + 2] = slot.n_sampled
                 if slot.pending is not None:  # mid-prefill: feed next chunk
                     take = min(slot.pending.size, self.chunk)
                     tokens[i, :take] = slot.pending[:take]
@@ -627,23 +661,20 @@ class StreamingEngine:
                 obs_metrics.inc("serve_decode_tokens_total", decode_toks)
 
         with obs_trace.span("engine.step"):
-            last, self.states = self._step_fn(
-                self.params, jnp.asarray(tokens), jnp.asarray(lengths),
-                self.states)
+            picked, last, self.states = self._step_fn(
+                self.params, jnp.asarray(batch), self.states, self.key)
+        # The tick's one host read: S token ids and S finite flags.
+        with obs_trace.span("engine.wait"):
+            picked = np.asarray(picked)
 
         # Slot quarantine: a poisoned carry (hardware fault, numerics bug)
-        # shows up as NaN/±inf in that slot's logits.  Detect per row on the
-        # (S, 1, V) last-valid logits — already host-bound for sampling —
-        # and reset ONLY the poisoned rows.  Healthy batch-mates never see a
-        # different code path, so their outputs stay byte-identical.
+        # shows up as NaN/±inf in that slot's logits.  The step checks each
+        # row of the (S, 1, V) last-valid logits on the device; reset ONLY
+        # the poisoned rows.  Healthy batch-mates never see a different
+        # code path, so their outputs stay byte-identical.
         if self.guard_logits:
-            # The tick's first host read, in two parts: the wait for the
-            # step, then the copy.  The split adds no synchronisation.
-            with obs_trace.span("engine.wait"):
-                jax.block_until_ready(last)
             with obs_trace.span("engine.guard"):
-                finite_rows = np.isfinite(
-                    np.asarray(last)).reshape(self.n_slots, -1).all(axis=1)
+                finite_rows = picked[:, 1]
                 poisoned = np.zeros((self.n_slots,), bool)
                 for i, slot in enumerate(self.active):
                     if slot is not None and not finite_rows[i]:
@@ -674,7 +705,7 @@ class StreamingEngine:
                         continue
                     slot.pending = None
                 ready.append(i)
-            toks = self._sample_ready(last, ready)
+            toks = self._sample_ready(picked, last, ready)
             now = time.perf_counter()
             for i in ready:
                 slot = self.active[i]
@@ -1068,28 +1099,21 @@ class StreamingEngine:
         with obs_trace.span("engine.reset"):
             self.states = self._reset_fn(self.states, jnp.asarray(mask))
 
-    def _sample_ready(self, last, ready: list[int]) -> dict[int, int]:
-        """Sample the rows in ``ready``; returns {row: token id}.
+    def _sample_ready(self, picked: np.ndarray, last,
+                      ready: list[int]) -> dict[int, int]:
+        """The tokens of the rows in ``ready``; returns {row: token id}.
 
-        jit-safe samplers take ONE fused vmapped call over all S rows
-        (non-ready rows sample garbage that is discarded — a fixed-shape
-        call beats a per-tick gather/recompile) with keys derived on
-        device; that single host sync replaces the per-slot eager
-        ``fold_in``+``int()`` round-trips that used to dominate the tick.
-        Custom samplers keep the eager per-row path and see concrete keys.
+        jit-safe samplers already sampled every row inside the step (keys
+        derived on device); their tokens are column 0 of ``picked``, read
+        with the finite flags in the tick's one host read.  Custom
+        samplers keep the eager per-row path on the step's device
+        ``last`` logits and see concrete keys.
         """
         if not ready:
             return {}
         if self._batched_sample is not None:
-            rids = np.zeros((self.n_slots,), np.int32)
-            steps = np.zeros((self.n_slots,), np.int32)
-            for i in ready:
-                slot = self.active[i]
-                rids[i] = slot.request_id
-                steps[i] = slot.n_sampled
-            toks = np.asarray(self._batched_sample(
-                last, self.key, jnp.asarray(rids), jnp.asarray(steps)))
-            return {i: int(toks[i, 0]) for i in ready}
+            obs_metrics.inc("serve_sampled_in_step_total", len(ready))
+            return {i: int(picked[i, 0]) for i in ready}
         out: dict[int, int] = {}
         for i in ready:
             slot = self.active[i]

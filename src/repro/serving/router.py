@@ -187,9 +187,10 @@ class ReplicatedRouter:
                     prefix_cache=prefix_cache)
             if i:
                 # Replicas are byte-identical computations: share replica
-                # 0's jitted step/reset (same cfg, n_slots, chunk, and the
-                # deterministic ⊕-identity init the reset closure bakes
-                # in), saving N-1 identical traces + compiles.
+                # 0's jitted step/reset (same cfg, n_slots, chunk, sampler,
+                # and the deterministic ⊕-identity init the reset closure
+                # bakes in; the sampling key and request ids are step
+                # arguments), saving N-1 identical traces + compiles.
                 eng._step_fn = self.engines[0]._step_fn
                 eng._reset_fn = self.engines[0]._reset_fn
             self.engines.append(eng)

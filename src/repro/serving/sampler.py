@@ -1,12 +1,11 @@
 """Token samplers: pure functions (logits, key) -> token ids.
 
 Samplers that are safe to trace (pure jnp/jax.random on their arguments,
-no host effects) carry ``jit_safe = True``; the engine then batches all
-slots' samples into one vmapped jitted call per tick instead of one eager
-per-slot call — the per-slot path costs ~1ms/slot/token in host dispatch
-and dominated the tick at 8 slots.  Custom samplers without the attribute
-(e.g. recording samplers in tests) keep the eager per-row path and see
-concrete keys.
+no host effects) carry ``jit_safe = True``; the streaming engine then
+samples every slot, vmapped, inside its jitted step, and the host reads
+back only the token ids.  Custom samplers without the attribute (e.g.
+recording samplers in tests) keep an eager per-row call on the step's
+logits and see concrete keys.
 """
 
 from __future__ import annotations

@@ -31,6 +31,8 @@ from repro.kernels.flash_attention import flash_attention, flash_attention_bwd
 from repro.launch import compile_cache
 from repro.models.factory import build
 from repro.models.lm import lm_prefill_chunk, lm_state_specs
+from repro.serving import StreamingEngine
+from repro.serving.sampler import greedy_sampler, temperature_sampler
 
 D = 96                          # phi3-mini head_dim
 SERVE = (8 * 32, 16)            # (rows, tokens): 8 slots x 32 heads, C = 16
@@ -156,6 +158,28 @@ def test_phi3_prefill_chunk_compiles(one_chip, monkeypatch):
     mask = _sds(one_chip, (8, 16), jnp.bool_)
     text = _compile(lambda p, t, m, s: lm_prefill_chunk(
         cfg, p, t, s, length_mask=m), params, tokens, mask, states)
+    jax.clear_caches()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("sampler", [greedy_sampler,
+                                     temperature_sampler(0.7, top_k=8)],
+                         ids=["greedy", "temperature"])
+def test_phi3_engine_step_compiles(one_chip, monkeypatch, sampler):
+    """The engine's whole tick program at the chat cell's shape, S = 16,
+    C = 16: the chunk step, the finite guard and the sampler."""
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")
+    jax.clear_caches()
+    cfg = get_config("phi3-mini-3.8b").replace(attn_mode="aaren")
+    api = build(cfg)
+    place = lambda tree: jax.tree.map(
+        lambda x: _sds(one_chip, x.shape, x.dtype), tree)
+    params = place(jax.eval_shape(api.init, jax.random.PRNGKey(0)))
+    eng = StreamingEngine(api, params, n_slots=16, chunk=16, sampler=sampler)
+    text = _compile(eng._step_fn, params,
+                    _sds(one_chip, (16, 16 + 3), jnp.int32),
+                    place(lm_state_specs(cfg, 16, 1)),
+                    place(jax.eval_shape(lambda: eng.key)))
     jax.clear_caches()
     assert "tpu_custom_call" in text
 

@@ -268,6 +268,19 @@ def test_readout_empty_state_is_defined():
                                rtol=1e-6)
 
 
+def _batch(chunk, lengths, rids=None, steps=None, tokens=None):
+    """The step's packed (S, chunk + 3) input: the tokens (0 by default),
+    then each row's valid length, request id and sample index."""
+    n = len(lengths)
+    batch = np.zeros((n, chunk + 3), np.int32)
+    if tokens is not None:
+        batch[:, :chunk] = tokens
+    batch[:, chunk] = lengths
+    batch[:, chunk + 1] = rids if rids is not None else 0
+    batch[:, chunk + 2] = steps if steps is not None else 0
+    return jnp.asarray(batch)
+
+
 def test_all_padding_step_is_safe(aaren_model, rng):
     """A slot scheduled with ``lengths == 0`` (all-padding row) used to
     gather last-valid logits at index ``lengths - 1 == -1`` — silently
@@ -283,23 +296,150 @@ def test_all_padding_step_is_safe(aaren_model, rng):
     eng.step()
     before = jax.tree.map(np.asarray, eng.states)
 
-    tokens = jnp.zeros((2, 4), jnp.int32)
-    lengths = jnp.asarray([0, 0], jnp.int32)      # every slot all-padding
-    last, after = eng._step_fn(eng.params, tokens, lengths, eng.states)
+    def step(lengths):
+        return eng._step_fn(eng.params, _batch(4, lengths), eng.states,
+                            eng.key)
+
+    picked, last, after = step([0, 0])           # every slot all-padding
     assert np.all(np.isfinite(np.asarray(last)))
+    np.testing.assert_array_equal(np.asarray(picked)[:, 1], [1, 1])
     for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
         np.testing.assert_allclose(np.asarray(b), a, rtol=0, atol=0)
 
     # Mixed tick: one real decode row next to an all-padding row must give
-    # the real row exactly the logits it gets when every slot is live —
-    # the padded row must not leak into it through any cross-row path.
-    last_live, _ = eng._step_fn(
-        eng.params, tokens, jnp.asarray([1, 1], jnp.int32), eng.states)
-    last_mixed, _ = eng._step_fn(
-        eng.params, tokens, jnp.asarray([1, 0], jnp.int32), eng.states)
+    # the real row exactly the logits (and token) it gets when every slot
+    # is live — the padded row must not leak into it through any cross-row
+    # path.
+    picked_live, last_live, _ = step([1, 1])
+    picked_mixed, last_mixed, _ = step([1, 0])
     assert np.all(np.isfinite(np.asarray(last_mixed)))
     np.testing.assert_allclose(np.asarray(last_mixed[0]),
                                np.asarray(last_live[0]), rtol=0, atol=0)
+    assert int(picked_mixed[0, 0]) == int(picked_live[0, 0])
+
+
+@pytest.mark.parametrize("sampler", [
+    greedy_sampler, temperature_sampler(0.7, top_k=8)],
+    ids=["greedy", "temperature"])
+def test_step_samples_like_sample_rows(aaren_model, rng, sampler):
+    """The step's tokens are, bit for bit, what ``_sample_rows`` (the wave
+    path) draws from the logits the step returns, per (request, step)."""
+    api, params = aaren_model
+    key = jax.random.PRNGKey(3)
+    eng = StreamingEngine(api, params, n_slots=3, chunk=4, sampler=sampler,
+                          key=key)
+    batch = _batch(4, [4, 1, 2], rids=[5, 9, 2], steps=[0, 3, 7],
+                   tokens=jax.random.randint(rng, (3, 4), 0, 64))
+    picked, last, _ = eng._step_fn(params, batch, eng.states, key)
+    want = engine_mod._sample_rows(sampler, last, key, [5, 9, 2], [0, 3, 7])
+    np.testing.assert_array_equal(np.asarray(picked)[:, 0],
+                                  np.asarray(want)[:, 0])
+    np.testing.assert_array_equal(np.asarray(picked)[:, 1], [1, 1, 1])
+
+
+def test_step_guard_flags_only_the_poisoned_row(aaren_model, rng):
+    """A NaN carry in slot 1 clears slot 1's finite flag alone; the other
+    rows' flags and tokens are those of the unpoisoned step."""
+    from repro.testing.faults import poison_engine_slot
+
+    api, params = aaren_model
+    eng = StreamingEngine(api, params, n_slots=3, chunk=4)
+    for i in range(3):
+        eng.submit(jax.random.randint(jax.random.fold_in(rng, i), (5,),
+                                      0, 64), 6)
+    eng.step()
+    batch = _batch(4, [1, 1, 1], rids=[0, 1, 2], steps=[1, 1, 1])
+    clean, _, _ = eng._step_fn(params, batch, eng.states, eng.key)
+    poison_engine_slot(eng, 1)
+    picked, last, _ = eng._step_fn(params, batch, eng.states, eng.key)
+    picked, clean = np.asarray(picked), np.asarray(clean)
+    np.testing.assert_array_equal(picked[:, 1], [1, 0, 1])
+    assert not np.isfinite(np.asarray(last[1])).all()
+    np.testing.assert_array_equal(picked[[0, 2]], clean[[0, 2]])
+
+
+def _recording(seen):
+    def sampler(logits, key):
+        seen.append(1)
+        return greedy_sampler(logits, key)
+    return sampler
+
+
+@pytest.mark.parametrize("jit_safe", [True, False], ids=["in_step", "eager"])
+def test_sampled_in_step_counter(aaren_model, rng, jit_safe):
+    """``serve_sampled_in_step_total`` counts every token a jit-safe sampler
+    drew inside the step, and none where a recording sampler (not
+    ``jit_safe``) samples on the host."""
+    from repro.obs.metrics import MetricsRegistry, use_metrics
+
+    api, params = aaren_model
+    seen = []
+    sampler = greedy_sampler if jit_safe else _recording(seen)
+    with use_metrics(MetricsRegistry()) as reg:
+        eng = StreamingEngine(api, params, n_slots=2, chunk=4,
+                              sampler=sampler)
+        for i, n in enumerate([3, 5, 2]):
+            eng.submit(jax.random.randint(jax.random.fold_in(rng, i), (6,),
+                                          0, 64), n)
+        out = eng.run()
+    emitted = sum(map(len, out.values()))
+    assert emitted == 10
+    if jit_safe:
+        assert reg.peek("serve_sampled_in_step_total") == emitted
+    else:
+        assert reg.peek("serve_sampled_in_step_total") is None
+        assert len(seen) == emitted
+
+
+class _CountedRead:
+    """Stands in for the step's small output: counts host reads."""
+
+    def __init__(self, x, reads):
+        self.x, self.reads = x, reads
+
+    def __array__(self, dtype=None, copy=None):
+        self.reads.append(1)
+        return np.asarray(self.x, dtype)
+
+
+class _Unreadable:
+    """Stands in for the step's logits: any use fails the test."""
+
+    def __getattr__(self, name):
+        raise AssertionError(f"the host touched the logits ({name})")
+
+    def __array__(self, dtype=None, copy=None):
+        raise AssertionError("the host copied the logits")
+
+    def __getitem__(self, idx):
+        raise AssertionError("the host indexed the logits")
+
+
+def test_decode_tick_one_upload_one_read(aaren_model, rng, capfd):
+    """With a jit-safe sampler, a tick that frees no slot uploads one array
+    (logged by JAX's transfer guard) and reads one back; the logits never
+    reach the host."""
+    api, params = aaren_model
+    eng = StreamingEngine(api, params, n_slots=2, chunk=4)
+    eng.warmup()
+    for i in range(2):
+        eng.submit(jax.random.randint(jax.random.fold_in(rng, i), (3,),
+                                      0, 64), 8)
+    assert eng.step() == 2                       # prefill + first tokens
+    reads = []
+    real = eng._step_fn
+
+    def spy(*args):
+        picked, _, states = real(*args)
+        return _CountedRead(picked, reads), _Unreadable(), states
+
+    eng._step_fn = spy
+    capfd.readouterr()
+    with jax.transfer_guard_host_to_device("log_explicit"):
+        assert eng.step() == 2                   # decode, no slot freed
+    assert capfd.readouterr().err.count("host-to-device transfer") == 1
+    assert reads == [1]
+    assert all(len(s.tokens) == 2 for s in eng.active)
 
 
 def test_masked_chunk_matches_sliced(rng):
