@@ -11,7 +11,13 @@ in ``jax.numpy`` at ``Precision.HIGHEST``; it imports nothing of the
 program.
 
 Weights come from ``lib/weights.py``, drawn from the seed again layer by
-layer, so the whole model never sits on the device in float32.
+layer, so the whole model never sits on the device in float32.  Which
+leaves a layer has, and at what scale they are drawn, is this module's
+table (:func:`layer_leaves`, :func:`top_leaves`); the harness finds it,
+and the parameters that multiply every token (:func:`matmul_params`),
+through the configuration file's ``"reference"``.  Another layout's
+reference brings its own table and may reuse this module's mixer and its
+serving and training loops, passing its own ``block``.
 
 ``precision="fp8"`` is the control: every matrix product takes its operands
 rounded to float8 e4m3, and passes their gradients back rounded to e5m2,
@@ -59,6 +65,57 @@ def _fp8_bwd(_, g):
 
 
 _fp8.defvjp(_fp8_fwd, _fp8_bwd)
+
+
+# ------------------------------------------------------------- the leaves
+
+
+def layer_leaves(cfg: dict, layer) -> dict:
+    """name -> (shape, std or "ones") of decoder layer ``layer``.
+
+    Every layer of this model has the same leaves, so ``layer`` may be
+    traced, as :func:`served_logits` passes it.
+    """
+    d, f = cfg["hidden_size"], cfg["intermediate_size"]
+    h, g, k = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+               cfg["head_dim"])
+    return {
+        "norm1.scale": ((d,), "ones"),
+        "mixer.query": ((d,), W.QUERY_STD),
+        "mixer.wq": ((d, h, k), d ** -0.5),
+        "mixer.wk": ((d, g, k), d ** -0.5),
+        "mixer.wv": ((d, g, k), d ** -0.5),
+        "mixer.wo": ((h, k, d), (h * k) ** -0.5),
+        "norm2.scale": ((d,), "ones"),
+        "mlp.wi_gate": ((d, f), d ** -0.5),
+        "mlp.wi_up": ((d, f), d ** -0.5),
+        "mlp.wo": ((f, d), f ** -0.5),
+    }
+
+
+def top_leaves(cfg: dict) -> dict:
+    """name -> (shape, std or "ones") of the leaves outside the layers."""
+    d, v = cfg["hidden_size"], cfg["vocab_size"]
+    return {
+        "embed.table": ((v, d), W.EMBED_STD),
+        "final_norm.scale": ((d,), "ones"),
+        "unembed.kernel": ((d, v), d ** -0.5),
+    }
+
+
+def matmul_params(cfg: dict) -> int:
+    """Parameters that multiply every token (keys, values, output, MLP, and
+    the unembedding): the N of 2 N and 6 N FLOPs a token.  The embedding
+    is a gather and the Aaren query projection runs once per layer and
+    call, so neither counts."""
+    d, f, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
+    h, g, k = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
+               cfg["head_dim"])
+    per_layer = 2 * d * g * k + h * k * d + 3 * d * f
+    return cfg["num_hidden_layers"] * per_layer + d * v
+
+
+# ------------------------------------------------------------------ layers
 
 
 def mm(eq: str, a, b, precision: str):
@@ -146,12 +203,18 @@ def layer(w, x, seg, cfg, precision):
                   precision)
 
 
+def dense_block(w, x, seg, cfg, precision):
+    """One layer as the loops below call it: (output, auxiliary loss or
+    None).  This model's layers add no auxiliary loss."""
+    return layer(w, x, seg, cfg, precision), None
+
+
 # ----------------------------------------------------------------- serving
 
 
 def served_logits(cfg: dict, seed: int, tokens: np.ndarray, seg: np.ndarray,
                   rows: np.ndarray, cols: np.ndarray,
-                  precision: str = "f32") -> np.ndarray:
+                  precision: str = "f32", block=dense_block) -> np.ndarray:
     """Logits (len(rows), V) at positions ``(rows, cols)`` of ``tokens``.
 
     tokens, seg: (B, N); the model runs layer by layer, each layer's
@@ -164,7 +227,8 @@ def served_logits(cfg: dict, seed: int, tokens: np.ndarray, seg: np.ndarray,
     top = jax.jit(lambda k: W.top_weights(cfg, k))(key)
     x = _embed(top["embed.table"], tokens)
     for l in range(cfg["num_hidden_layers"]):
-        x = _served_layer(x, seg, key, l, cfg=_Frozen(cfg), precision=precision)
+        x = _served_layer(x, seg, key, l, cfg=_Frozen(cfg), precision=precision,
+                          block=block)
     return np.asarray(_served_head(x, top["final_norm.scale"],
                                    top["unembed.kernel"], jnp.asarray(rows),
                                    jnp.asarray(cols), cfg=_Frozen(cfg),
@@ -183,9 +247,9 @@ def _embed(table, tokens):
     return table[tokens]
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "precision"))
-def _served_layer(x, seg, key, l, *, cfg, precision):
-    return layer(W.layer_weights(cfg, key, l), x, seg, cfg, precision)
+@functools.partial(jax.jit, static_argnames=("cfg", "precision", "block"))
+def _served_layer(x, seg, key, l, *, cfg, precision, block):
+    return block(W.layer_weights(cfg, key, l), x, seg, cfg, precision)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "precision"))
@@ -208,8 +272,11 @@ def init_params(cfg: dict, key) -> dict:
     }
 
 
-def loss(params: dict, batch: dict, cfg: dict, precision: str):
-    """Mean next-token cross entropy over targets in the same document."""
+def loss(params: dict, batch: dict, cfg: dict, precision: str,
+         block=dense_block, aux_weight: float = 0.0):
+    """Mean next-token cross entropy over targets in the same document,
+    plus ``aux_weight`` times the mean over all layers of the auxiliary
+    losses that ``block`` returns, where it returns any."""
     f32 = lambda t: jax.tree.map(lambda a: a.astype(jnp.float32), t)
     tokens, seg = batch["tokens"], batch["segment_ids"]
     top = f32(params["top"])
@@ -217,10 +284,13 @@ def loss(params: dict, batch: dict, cfg: dict, precision: str):
 
     @jax.checkpoint
     def step(w, x):          # weights as stored; float32 inside, recomputed
-        return layer(f32(w), x, seg, cfg, precision)
+        return block(f32(w), x, seg, cfg, precision)
 
+    aux = []
     for w in params["layers"]:
-        x = step(w, x)
+        x, a = step(w, x)
+        if a is not None:
+            aux.append(a)
     h = rmsnorm(x, top["final_norm.scale"], cfg["rms_norm_eps"])[:, :-1]
     tgt = tokens[:, 1:]
     valid = ((seg[:, 1:] == seg[:, :-1]) & (seg[:, 1:] != 0)
@@ -244,7 +314,10 @@ def loss(params: dict, batch: dict, cfg: dict, precision: str):
     sums = jax.lax.map(block_nll, (h.reshape(nb, LOSS_BLOCK, d),
                                    tgt.reshape(nb, LOSS_BLOCK),
                                    valid.reshape(nb, LOSS_BLOCK)))
-    return jnp.sum(sums) / jnp.maximum(jnp.sum(valid), 1)
+    ce = jnp.sum(sums) / jnp.maximum(jnp.sum(valid), 1)
+    if not aux:
+        return ce
+    return ce + aux_weight * sum(aux) / len(params["layers"])
 
 
 def leaf_norms(tree: dict) -> dict:
@@ -266,11 +339,13 @@ def host_leaves(tree: dict) -> dict:
     return out
 
 
-def train(cfg: dict, seed: int, batches: list, precision: str = "f32"):
+def train(cfg: dict, seed: int, batches: list, precision: str = "f32",
+          block=dense_block, aux_weight: float = 0.0):
     """AdamW over ``batches`` from the seed's weights, as the configuration
-    states it.  Returns (losses, the first clipped gradient's leaf norms,
-    leaf norms of the parameters' change after the last step, the first
-    clipped gradient's leaves on the host)."""
+    states it, on :func:`loss` with ``block`` and ``aux_weight``.
+    Returns (losses, the first clipped gradient's leaf norms, leaf norms
+    of the parameters' change after the last step, the first clipped
+    gradient's leaves on the host)."""
     opt = cfg["optimizer"]
     b1, b2, eps, wd, lr = (opt["b1"], opt["b2"], opt["eps"],
                            opt["weight_decay"], opt["lr"])
@@ -280,7 +355,8 @@ def train(cfg: dict, seed: int, batches: list, precision: str = "f32"):
     batches = [{k: jnp.asarray(a) for k, a in b.items()} for b in batches]
 
     def clipped_grad(params, batch):
-        val, g = jax.value_and_grad(loss)(params, batch, cfg, precision)
+        val, g = jax.value_and_grad(loss)(params, batch, cfg, precision,
+                                          block, aux_weight)
         gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(x))
                              for x in jax.tree.leaves(g)))
         scale = jnp.minimum(1.0, opt["max_grad_norm"] / jnp.maximum(gnorm,

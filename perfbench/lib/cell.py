@@ -4,6 +4,7 @@ is adding files; nothing here names one."""
 
 from __future__ import annotations
 
+import functools
 import importlib
 import importlib.util
 import json
@@ -79,11 +80,19 @@ def driver(name: str):
 
 
 def reference(config: dict):
-    """The plain reference module named by the configuration file."""
-    path = ROOT / "configs" / f"{_checked(config['reference'])}.py"
+    """The plain reference module named by the configuration file: its
+    model, its table of leaves and its count of parameters.  Loaded once
+    a process, so that its jitted functions keep their compiled code."""
+    return _reference(_checked(config["reference"]))
+
+
+@functools.cache
+def _reference(name: str):
+    path = ROOT / "configs" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no reference named {name!r} ({path})")
     spec = importlib.util.spec_from_file_location(
-        "perfbench_ref_" + re.sub(r"[^A-Za-z0-9_]", "_", config["reference"]),
-        path)
+        "perfbench_ref_" + re.sub(r"[^A-Za-z0-9_]", "_", name), path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod

@@ -3,8 +3,8 @@
 Model FLOPs follow the usual convention (Kaplan et al., arXiv:2001.08361;
 the same arithmetic as the program's ``roofline/analysis.model_flops``):
 2 N per token for a forward pass and 6 N for a training step, N the
-parameters that multiply every token.  The embedding is a gather and the
-Aaren query projection runs once per layer and call, so neither counts.
+parameters that multiply every token, which the configuration's reference
+module counts (``matmul_params``).
 
 The Aaren scan kernels are counted at the least work the algorithm needs:
 what they must read and write in HBM (float32), and the floating-point
@@ -16,16 +16,6 @@ is, if anything, low.
 from __future__ import annotations
 
 F32 = 4
-
-
-def matmul_params(cfg: dict) -> int:
-    """Parameters that multiply every token (keys, values, output, MLP, and
-    the unembedding)."""
-    d, f, v = cfg["hidden_size"], cfg["intermediate_size"], cfg["vocab_size"]
-    h, g, k = (cfg["num_attention_heads"], cfg["num_key_value_heads"],
-               cfg["head_dim"])
-    per_layer = 2 * d * g * k + h * k * d + 3 * d * f
-    return cfg["num_hidden_layers"] * per_layer + d * v
 
 
 def model_flops(n_params: int, n_tokens: float, kind: str) -> float:

@@ -18,17 +18,36 @@ FIELDS = {
     "vocab_size": "vocab",
     "attn_mode": "attn_mode",
     "dtype": "param_dtype",
+    "num_experts": "n_experts",
+    "num_experts_per_tok": "n_experts_per_tok",
+    "moe_intermediate_size": "moe_d_ff",
 }
+# Fields that the harness sets from other keys of the file.
+SET_BY_FILE = frozenset(FIELDS.values()) | {"compute_dtype", "remat"}
 
 
 def arch_config(cfg: dict):
-    """The program's ArchConfig for a configuration file."""
+    """The program's ArchConfig for a configuration file.
+
+    The published keys of :data:`FIELDS` set their fields; an optional
+    ``"program"`` object sets any other field by its own name (lists are
+    taken as tuples), such as ``pattern``, ``mlp_pattern`` or
+    ``capacity_factor``.  It may not set a field that the file states
+    under another key.
+    """
     from repro.configs import get_config
 
     over = {FIELDS[k]: cfg[k] for k in FIELDS if k in cfg}
     over["compute_dtype"] = cfg["dtype"]
     if "remat" in cfg:
         over["remat"] = cfg["remat"]
+    program = cfg.get("program", {})
+    both = sorted(SET_BY_FILE & set(program))
+    if both:
+        raise ValueError(f"'program' sets {both}, which the configuration "
+                         "file states under its own keys")
+    over.update((k, tuple(v) if isinstance(v, list) else v)
+                for k, v in program.items())
     arch = get_config(cfg["program_config"]).replace(**over)
     if cfg.get("tie_word_embeddings", False) != arch.tie_embeddings:
         raise ValueError("tie_word_embeddings differs from the program's")

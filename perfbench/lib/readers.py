@@ -7,6 +7,7 @@ import re
 import numpy as np
 
 from lib import flops
+from lib.cell import reference
 from lib.profile import op_name
 
 
@@ -34,7 +35,8 @@ def mfu(run: dict, kind: str):
     """Model FLOPs of the window's tokens per second over the chips' peak."""
     if run["peaks"] is None:
         return None
-    n = flops.matmul_params(run["cell"]["config"])
+    cfg = run["cell"]["config"]
+    n = reference(cfg).matmul_params(cfg)
     achieved = flops.model_flops(n, rate(run), kind)
     return 100.0 * achieved / (run["chips"] * run["peaks"]["bf16_flops_per_s"])
 

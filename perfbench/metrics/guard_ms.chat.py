@@ -1,6 +1,6 @@
-"""Median ms a tick spends in the engine's logits guard (``engine.guard``):
-the host copy of the (slots, 1, vocab) logits, the isfinite check and the
-quarantine."""
+"""Median ms a tick spends in the engine's guard (``engine.guard``):
+reading the finite flags that the jitted step returns with its tokens,
+and the quarantine of a slot whose logits were not finite."""
 
 from lib.spans import median_ms
 

@@ -11,6 +11,7 @@ BENCH = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(BENCH)]
 
 from lib import device, flops  # noqa: E402
+from lib.cell import reference  # noqa: E402
 
 
 def config(name):
@@ -20,11 +21,11 @@ def config(name):
 def test_matmul_params_at_phi3_shapes():
     # per layer: wk, wv 3072 x 32 x 96 each, wo 32 x 96 x 3072, MLP 3 x
     # 3072 x 8192 = 28,311,552 + 75,497,472 = 103,809,024; unembedding
-    # 3072 x 32064 = 98,500,608.
-    assert flops.matmul_params(config("phi3-mini-3.8b")) == (
-        32 * 103_809_024 + 98_500_608)
-    assert flops.matmul_params(config("phi3-mini-3.8b-4l")) == (
-        4 * 103_809_024 + 98_500_608)
+    # 3072 x 32064 = 98,500,608.  The configuration's reference counts N.
+    for name, layers in (("phi3-mini-3.8b", 32), ("phi3-mini-3.8b-4l", 4)):
+        cfg = config(name)
+        assert reference(cfg).matmul_params(cfg) == (
+            layers * 103_809_024 + 98_500_608)
 
 
 def test_model_flops():
