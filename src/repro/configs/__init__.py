@@ -5,6 +5,7 @@ from repro.configs import (  # noqa: F401  (import for registration)
     dbrx_132b,
     gemma3_27b,
     llama3_405b,
+    mellum2_12b_a2p5b,
     mamba2_1p3b,
     minitron_8b,
     phi3_mini_3p8b,

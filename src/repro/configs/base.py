@@ -49,7 +49,11 @@ class ArchConfig:
     n_experts_per_tok: int = 0
     moe_d_ff: int = 0  # per-expert hidden size (qwen3's 768 is per expert)
     capacity_factor: float = 1.25
-    router_jitter: float = 0.0
+    # A chip's share under expert parallelism: the router scores
+    # ``n_routed_experts`` experts (0: ``n_experts``, all of them held), and
+    # this chip holds the ``n_experts`` of them from ``expert_offset`` on.
+    n_routed_experts: int = 0
+    expert_offset: int = 0
 
     # SSM (mamba2)
     ssm_state: int = 0
@@ -98,10 +102,19 @@ class ArchConfig:
         for m in self.mlp_pattern:
             if m not in MLPS:
                 raise ValueError(f"unknown mlp {m!r}")
+        if self.expert_offset + self.n_experts > self.router_width:
+            raise ValueError(
+                f"{self.n_experts} experts held from {self.expert_offset} "
+                f"on, but the router scores {self.router_width}")
 
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // max(self.n_heads, 1)
+
+    @property
+    def router_width(self) -> int:
+        """Experts the router scores; ``n_experts`` of them are held here."""
+        return self.n_routed_experts or self.n_experts
 
     @property
     def d_inner(self) -> int:  # SSM inner width

@@ -9,6 +9,9 @@ Dispatch policy (DESIGN.md §Dispatch)
   cannot lower on the CPU backend, so the dry-run compiles the jnp path —
   its HLO cost analysis is what the roofline reads, and DESIGN.md §Perf
   documents the kernel-vs-jnp delta analytically.
+* ``expert_gmm`` (the dropless expert layer's grouped matrix product) runs
+  the Pallas kernel of ``expert_gmm.py`` on TPU and a masked dense
+  ``einsum`` over the held experts elsewhere; it is forward-only (serving).
 * ``REPRO_KERNEL_MODE`` env: ``auto`` (default) | ``pallas`` | ``interpret``
   (kernels in interpret mode — used by kernel-parity tests on the CPU; it
   raises on a TPU, where it would hide the device) | ``jnp``.
@@ -45,6 +48,7 @@ from repro.core.scan_attention import (
 )
 from repro.kernels import aaren_scan as _aaren_kernel
 from repro.kernels import aaren_scan_bwd as _aaren_bwd_kernel
+from repro.kernels import expert_gmm as _gmm_kernel
 from repro.kernels import flash_attention as _flash_kernel
 from repro.obs.trace import span as _span
 
@@ -429,3 +433,53 @@ def flash_mha(
     o = _flash_core(qt, kt, vt, q_lens, kv_lens, q_segment_ids,
                     kv_segment_ids, causal, window, float(scale))
     return jnp.swapaxes(o, 1, 2)
+
+
+# ---------------------------------------------------------------------------
+# Grouped matrix product over the held experts: (lhs, rhs, sizes) -> out
+# ---------------------------------------------------------------------------
+
+
+def _expert_gmm_jnp(lhs, rhs, group_sizes, group_offset, rhs_up=None):
+    """Every row times every held expert's weights, kept where the row is
+    that expert's: H times the kernel's work, for the CPU and the dry-run.
+    Rows outside the held groups read 0."""
+    n_held = rhs.shape[0]
+    ends = jnp.cumsum(group_sizes.astype(jnp.int32))
+    lo = jax.lax.dynamic_slice(ends - group_sizes, (group_offset,),
+                               (n_held,))
+    hi = jax.lax.dynamic_slice(ends, (group_offset,), (n_held,))
+    rows = jnp.arange(lhs.shape[0])[:, None]
+    mine = ((rows >= lo) & (rows < hi)).astype(jnp.float32)    # (m, H)
+    prod = lambda w: jnp.einsum("mk,hkn->mhn", lhs, w,
+                                preferred_element_type=jnp.float32)
+    y = prod(rhs)
+    if rhs_up is not None:
+        y = jax.nn.silu(y) * prod(rhs_up)
+    return jnp.einsum("mhn,mh->mn", y, mine).astype(lhs.dtype)
+
+
+def expert_gmm(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+               group_offset, rhs_up: jax.Array | None = None,
+               layer=None) -> jax.Array:
+    """Grouped matrix product over the experts held here (no gradient).
+
+    lhs: (m, K) rows sorted by group; rhs (and ``rhs_up``, which makes it
+    ``silu(lhs @ rhs) * (lhs @ rhs_up)``): (H, K, N), the weights of groups
+    ``group_offset .. group_offset + H - 1``, or (L, H, K, N) stacked over
+    layers with ``layer`` naming one (the kernel reads it in place);
+    group_sizes: (G,) int32 rows of every group.  Returns (m, N); rows
+    outside the held groups are unspecified (0 on the jnp path, unwritten
+    by the kernel).
+    """
+    mode = kernel_mode()
+    offset = jnp.asarray(group_offset, jnp.int32)
+    sizes = jnp.asarray(group_sizes, jnp.int32)
+    with _span(f"expert_gmm.{mode}"):
+        if mode == "jnp":
+            if layer is not None:
+                rhs = rhs[layer]
+                rhs_up = None if rhs_up is None else rhs_up[layer]
+            return _expert_gmm_jnp(lhs, rhs, sizes, offset, rhs_up)
+        return _gmm_kernel.expert_gmm(lhs, rhs, sizes, offset, rhs_up, layer,
+                                      interpret=mode == "interpret")

@@ -193,7 +193,8 @@ def _apply_mixer_chunk(p, h, state, sig, cfg, mask):
         f"chunked prefill needs a position-free carry; {mixer!r} has none")
 
 
-def _apply_mlp(p, x, sig, cfg, want_aux: bool, decode: bool = False):
+def _apply_mlp(p, x, sig, cfg, want_aux: bool):
+    """The training path's MLP (expert layers: the capacity dispatch)."""
     mlp = sig[1]
     if mlp == "none":
         return x, dict(ZERO_AUX)
@@ -203,12 +204,21 @@ def _apply_mlp(p, x, sig, cfg, want_aux: bool, decode: bool = False):
     if mlp == "gelu":
         return x + apply_gelu_mlp(p["mlp"], h), dict(ZERO_AUX)
     if mlp == "moe":
-        y, aux = moe_mod.apply_moe(p["mlp"], h, cfg, return_aux=True,
-                                   decode=decode)
+        y, aux = moe_mod.apply_moe(p["mlp"], h, cfg, return_aux=True)
         if not want_aux:
             aux = dict(ZERO_AUX)
         return x + y, aux
     raise ValueError(mlp)
+
+
+def _serve_mlp(p, x, sig, cfg, mask=None, layer=None):
+    """The serving paths' MLP: (x, expert counts or None).  Expert layers
+    take the dropless dispatch, so no token is dropped."""
+    if sig[1] == "moe":
+        y, counts = moe_mod.apply_moe_dropless(
+            p["mlp"], apply_norm(p["norm2"], x, cfg.norm), cfg, mask, layer)
+        return x + y, counts
+    return _apply_mlp(p, x, sig, cfg, want_aux=False)[0], None
 
 
 def block_sequence(p: dict, x: jax.Array, sig: Sig, cfg: ArchConfig, *,
@@ -235,26 +245,24 @@ def block_step(p: dict, x_t: jax.Array, state, sig: Sig, cfg: ArchConfig):
     """One-token decode.  Returns (x_t, new_state)."""
     h = apply_norm(p["norm1"], x_t, cfg.norm)
     y, new_state = _apply_mixer_step(p["mixer"], h, state, sig, cfg)
-    x_t = x_t + y
-    x_t, _ = _apply_mlp(p, x_t, sig, cfg, want_aux=False, decode=True)
+    x_t, _ = _serve_mlp(p, x_t + y, sig, cfg)
     return x_t, new_state
 
 
 def block_chunk(p: dict, x: jax.Array, state, sig: Sig, cfg: ArchConfig, *,
-                mask: jax.Array | None = None):
-    """Fixed-shape chunk through one block's carry.  Returns (x, new_state).
+                mask: jax.Array | None = None, layer=None):
+    """Fixed-shape chunk through one block's carry.
 
     x: (B, C, D); mask: (B, C) valid-position flags (None = all valid).
-    Norms and dense MLPs are position-wise, so padded positions cannot leak
-    into valid ones; only the mixer needs the mask.  MoE caveat: padded
-    tokens are routed too — they can never displace a valid token (capacity
-    rank is stable in token order and the valid prefix comes first), but
-    per-chunk capacity means *dropping* of valid tokens may differ from
-    one-shot prefill when capacity binds (inherent to chunked MoE serving;
-    irrelevant when capacity_factor leaves headroom).
+    Norms and MLPs are position-wise, so padded positions cannot leak into
+    valid ones; the mixer takes the mask, and an expert layer computes no
+    pair of a padded position.  No token is dropped, so the output does
+    not depend on C.  ``layer``: an expert layer's weights come stacked
+    over the periods, read at this index.  Returns (x, new_state,
+    counts): ``counts`` (2,) int32 for an expert layer
+    (``moe.apply_moe_dropless``), else None.
     """
     h = apply_norm(p["norm1"], x, cfg.norm)
     y, new_state = _apply_mixer_chunk(p["mixer"], h, state, sig, cfg, mask)
-    x = x + y
-    x, _ = _apply_mlp(p, x, sig, cfg, want_aux=False, decode=x.shape[1] == 1)
-    return x, new_state
+    x, counts = _serve_mlp(p, x + y, sig, cfg, mask, layer)
+    return x, new_state, counts

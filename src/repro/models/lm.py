@@ -275,6 +275,33 @@ def lm_decode_step(cfg: ArchConfig, params: dict, token_t: jax.Array,
     return logits, new_states
 
 
+EXPERT_WEIGHTS = ("wi_gate", "wi_up", "wo")
+
+
+def _hold_experts(period_params: tuple, sigs) -> tuple[tuple, list]:
+    """(the period parameters without the expert weights, and per position
+    the expert weights stacked over the periods, or None)."""
+    moving, held = [], []
+    for p, sig in zip(period_params, sigs):
+        if sig[1] != "moe":
+            moving.append(p)
+            held.append(None)
+            continue
+        mlp = {n: v for n, v in p["mlp"].items() if n not in EXPERT_WEIGHTS}
+        held.append({n: p["mlp"][n] for n in EXPERT_WEIGHTS})
+        moving.append(dict(p, mlp=mlp))
+    return tuple(moving), held
+
+
+def _merge_counts(counts: list):
+    """Expert counts of several layers: pairs summed, the straggler's max.
+    ``counts`` holds (2,) or (L, 2) int32 arrays; None when it is empty."""
+    if not counts:
+        return None
+    c = jnp.concatenate([jnp.reshape(x, (-1, 2)) for x in counts])
+    return jnp.stack([jnp.sum(c[:, 0]), jnp.max(c[:, 1])])
+
+
 def lm_prefill_chunk(cfg: ArchConfig, params: dict, tokens: jax.Array,
                      states: dict, *, length_mask: jax.Array | None = None):
     """Advance per-layer carries by one fixed-shape chunk of tokens.
@@ -282,7 +309,10 @@ def lm_prefill_chunk(cfg: ArchConfig, params: dict, tokens: jax.Array,
     tokens: (B, C) int32; states: decode-state tree (layout of
     :func:`lm_decode_step`); length_mask: (B, C) bool, True at valid
     positions (a *prefix* per row — row i carries ``lengths[i]`` real tokens,
-    the rest is padding).  Returns (logits (B, C, V) f32, new states).
+    the rest is padding).  Returns (logits (B, C, V) f32, new states), and
+    for a model with expert layers also (2,) int32: the (token, expert)
+    pairs of valid positions computed by the held experts over all
+    layers, and the most any held expert took at one layer.
 
     This is the serving hot path: the chunk shape is static, so the engine
     traces exactly one step function per (B, C) and serves every prompt
@@ -295,46 +325,67 @@ def lm_prefill_chunk(cfg: ArchConfig, params: dict, tokens: jax.Array,
     """
     n_periods, n_rest = cfg.layer_plan()
     sigs = _sigs(cfg)
+    expert_counts = "moe" in cfg.mlp_pattern
     compute_dtype = jnp.dtype(cfg.compute_dtype)
     x = apply_embed(params["embed"], tokens, compute_dtype)
 
     new_states: dict[str, Any] = {}
+    counts: list = []
     if n_periods:
+        # Expert weights are not scanned: the grouped kernel reads each
+        # layer's experts in place, given the period's index, where a scan
+        # slice would copy them every tick.
+        moving, held = _hold_experts(params["periods"], sigs)
 
         def chunk_fn(x_c, scanned):
-            period_params, period_states = scanned
-            outs = []
+            period_params, period_states, *index = scanned
+            outs, cnts = [], []
             for pos, sig in enumerate(sigs):
-                x_c, st = blocks.block_chunk(
-                    period_params[pos], x_c, period_states[pos], sig, cfg,
-                    mask=length_mask)
+                p = period_params[pos]
+                if held[pos] is not None:
+                    p = dict(p, mlp=dict(p["mlp"], **held[pos]))
+                x_c, st, cnt = blocks.block_chunk(
+                    p, x_c, period_states[pos], sig, cfg, mask=length_mask,
+                    layer=index[0] if held[pos] is not None else None)
                 outs.append(st)
+                if cnt is not None:
+                    cnts.append(cnt)
+            if expert_counts:
+                return x_c, (tuple(outs), _merge_counts(cnts))
             return x_c, tuple(outs)
 
+        scanned = (moving, states["periods"])
+        if any(h is not None for h in held):
+            scanned += (jnp.arange(n_periods),)
         if cfg.scan_layers:
-            x, per_states = jax.lax.scan(
-                chunk_fn, x, (params["periods"], states["periods"]))
+            x, ys = jax.lax.scan(chunk_fn, x, scanned)
         else:
-            sts = []
+            outs = []
             for i in range(n_periods):
-                x, st = chunk_fn(x, jax.tree.map(
-                    lambda a: a[i], (params["periods"], states["periods"])))
-                sts.append(st)
-            per_states = jax.tree.map(lambda *xs: jnp.stack(xs), *sts)
-        new_states["periods"] = per_states
+                x, y = chunk_fn(x, jax.tree.map(lambda a: a[i], scanned))
+                outs.append(y)
+            ys = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
+        if expert_counts:
+            ys, per_counts = ys
+            counts.append(per_counts)
+        new_states["periods"] = ys
     if n_rest:
         rest_states = []
         for i in range(n_rest):
             sig = sigs[i % len(sigs)]
-            x, st = blocks.block_chunk(
+            x, st, cnt = blocks.block_chunk(
                 params["rest"][i], x, states["rest"][i], sig, cfg,
                 mask=length_mask)
             rest_states.append(st)
+            if cnt is not None:
+                counts.append(cnt)
         new_states["rest"] = tuple(rest_states)
 
     x = apply_norm(params["final_norm"], x, cfg.norm)
     logits = apply_unembed(
         params.get("unembed"), params["embed"], x, cfg.logit_softcap)
+    if expert_counts:
+        return logits, new_states, _merge_counts(counts)
     return logits, new_states
 
 

@@ -425,6 +425,7 @@ class StreamingEngine:
         self.states = self._init_states
         batch_axes = lm_state_batch_axes(cfg)
         self._batch_axes = batch_axes
+        self._expert_layers = "moe" in cfg.mlp_pattern
 
         def step(pr, batch, states, key):
             """One tick: advance the mixed batch, then guard and sample.
@@ -435,13 +436,16 @@ class StreamingEngine:
             ``(picked, last, new_states)``: ``picked`` (S, 2) int32 holds
             each row's sampled token and whether its logits are all
             finite; ``last`` (S, 1, V) is the last-valid logits, left on
-            the device for samplers that are not ``jit_safe``.
+            the device for samplers that are not ``jit_safe``.  A model
+            with expert layers appends one row to ``picked``: the tick's
+            (token, expert) pairs on the held experts and the most that
+            one held expert took at one layer.
             """
             tokens = batch[:, :chunk]
             lengths = batch[:, chunk]
             rids, steps = batch[:, chunk + 1], batch[:, chunk + 2]
             mask = jnp.arange(chunk)[None, :] < lengths[:, None]
-            logits, new_states = lm_prefill_chunk(
+            logits, new_states, *counts = lm_prefill_chunk(
                 cfg, pr, tokens, states, length_mask=mask)
             # An all-padding row (lengths == 0) keeps its carry bit-for-bit.
             # The ⊕-identity mask guarantees this *mathematically* but not
@@ -484,6 +488,8 @@ class StreamingEngine:
                 toks = sample(last, key, rids, steps)[:, 0]
             picked = jnp.stack([toks.astype(jnp.int32),
                                 finite.astype(jnp.int32)], axis=1)
+            if counts:          # a model with expert layers
+                picked = jnp.concatenate([picked, counts[0][None]], axis=0)
             return picked, last, new_states
 
         def reset(states, mask):
@@ -663,9 +669,15 @@ class StreamingEngine:
         with obs_trace.span("engine.step"):
             picked, last, self.states = self._step_fn(
                 self.params, jnp.asarray(batch), self.states, self.key)
-        # The tick's one host read: S token ids and S finite flags.
+        # The tick's one host read: S token ids and S finite flags (and
+        # the expert counts, for a model with expert layers).
         with obs_trace.span("engine.wait"):
             picked = np.asarray(picked)
+        if self._expert_layers:
+            pairs, load = (int(v) for v in picked[-1])
+            picked = picked[:-1]
+            obs_metrics.inc("serve_moe_pairs_total", pairs)
+            obs_metrics.set_gauge("serve_moe_expert_load_max", load)
 
         # Slot quarantine: a poisoned carry (hardware fault, numerics bug)
         # shows up as NaN/±inf in that slot's logits.  The step checks each

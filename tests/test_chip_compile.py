@@ -19,6 +19,7 @@ The compile-cache helper's tests sit at the end of the file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -182,6 +183,28 @@ def test_phi3_engine_step_compiles(one_chip, monkeypatch, sampler):
                     place(jax.eval_shape(lambda: eng.key)))
     jax.clear_caches()
     assert "tpu_custom_call" in text
+
+
+def test_mellum_engine_step_compiles(one_chip, monkeypatch):
+    """The Mellum cell's tick program: 28 layers at the published widths,
+    16 of 64 experts held, S = 64, C = 16; the grouped expert kernel twice
+    a layer (gate and up fused, then down), in the scanned period of four
+    layers."""
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")
+    jax.clear_caches()
+    cfg = get_config("mellum2-12b-a2.5b").replace(
+        n_experts=16, n_routed_experts=64)
+    api = build(cfg)
+    place = lambda tree: jax.tree.map(
+        lambda x: _sds(one_chip, x.shape, x.dtype), tree)
+    params = place(jax.eval_shape(api.init, jax.random.PRNGKey(0)))
+    eng = StreamingEngine(api, params, n_slots=64, chunk=16)
+    text = _compile(eng._step_fn, params,
+                    _sds(one_chip, (64, 16 + 3), jnp.int32),
+                    place(lm_state_specs(cfg, 64, 1)),
+                    place(jax.eval_shape(lambda: eng.key)))
+    jax.clear_caches()
+    assert len(set(re.findall(r"%_expert_gmm\.\d+ = ", text))) == 8
 
 
 # ------------------------------------------------------- compile cache
